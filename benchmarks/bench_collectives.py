@@ -78,17 +78,18 @@ def _child() -> Dict:
     from repro.analysis import collective_summary
     from repro.fl.round import build_fl_round, fl_init
     from repro.fl.sharding import make_fl_shardings
+    from repro.launch.mesh import make_mesh
     from repro.models.build import vision_syn_spec
     from repro.models.cnn import MNIST_SPEC, make_paper_model
 
     assert len(jax.devices()) == 8, \
         f"child expected 8 forced host devices, got {len(jax.devices())}"
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     sh = make_fl_shardings(mesh)
     # width-matched mesh: client axis of size 1 -> each "shard" runs the
     # full vmap width, isolating the shard_map plumbing from XLA's
     # width-dependent batched-dot lowering
-    mesh_w = jax.make_mesh((1, 8), ("data", "model"))
+    mesh_w = make_mesh((1, 8), ("data", "model"))
     sh_w = make_fl_shardings(mesh_w)
 
     model = make_paper_model("mlp", MNIST_SPEC)

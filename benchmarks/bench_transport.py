@@ -14,12 +14,16 @@ the in-process oracle. Four gates:
   wire carries not one byte more than the accounted one);
 * **socket bitwise**: a live multi-process run — including injected frame
   drops (``rx_filter``) and a SIGKILLed worker — produces params, per-
-  client EF, and delivered masks bitwise equal to the in-process masked
-  oracle (``build_fl_round`` + ``fault_schedule_fn``) on the identical
-  fault pattern;
+  client EF, and delivered masks bitwise equal to a width-matched
+  in-process oracle on the identical fault pattern (``replay_live_run``:
+  each client's worker step at width 1, as its worker runs it, and the
+  live loop's server step), and within two roundings of the largest
+  weight of the vmapped engine (``build_fl_round`` + ``fault_schedule_fn``),
+  which runs the clients at width N;
 * **residual conservation**: for a round whose frame the wire ate, the
   EF identity ``e' = u - delivered`` holds exactly (``delivered = 0``,
-  so ``e' == u``) — checked on the oracle at ``atol=0`` and transferred
+  so ``e' == u``) — checked at ``atol=0`` on the masked in-process
+  pipeline (``build_fl_round`` + ``fault_schedule_fn``) and transferred
   to the wire by the EF-bitwise gate;
 * **straggle isolation**: with one worker sleeping ``STRAGGLE_S`` per
   round and a tight deadline, measured round wall clock stays bounded by
@@ -71,11 +75,6 @@ WARM_DEADLINE_S = 600.0              # round-0 jit compile inside workers
 
 def _ravel(tree) -> np.ndarray:
     return np.concatenate([np.asarray(l, np.float32).ravel()
-                           for l in jax.tree_util.tree_leaves(tree)])
-
-
-def _ravel_row(tree, i) -> np.ndarray:
-    return np.concatenate([np.asarray(l[i], np.float32).ravel()
                            for l in jax.tree_util.tree_leaves(tree)])
 
 
@@ -223,6 +222,7 @@ def _tiny_scenarios() -> Dict:
     from repro.data.partition import dirichlet_partition
     from repro.data.synthetic import make_class_image_dataset
     from repro.fl.engine import device_pools
+    from repro.launch.worker import replay_live_run, vision_setup
 
     spec, fl = _tiny_problem()
     run = RunConfig(fl=fl, wire="codec", transport="socket",
@@ -237,12 +237,8 @@ def _tiny_scenarios() -> Dict:
     pools = device_pools(parts)
     plan, part = _fault_plans()
 
-    # oracle
-    engine = _tiny_oracle(model, params, strategy, codec, fl, train, pools,
-                          plan, part)
-    state = engine.init_state(params, TINY_N, strategy)
-    state, _ = engine.run_loop(state, TINY_ROUNDS)
-    oracle_params, oracle_ef = jax.device_get((state.params, state.ef))
+    setup = vision_setup(run, model="mlp", spec=spec, train_size=TINY_TRAIN)
+    oracle_params, oracle_efs = replay_live_run(setup, params, plan, part)
 
     # live: the wire eats DROPS frames; the worker dies mid-run
     def rx_filter(cid, rnd, buf):
@@ -261,7 +257,6 @@ def _tiny_scenarios() -> Dict:
     # _socket_run spawns procs internally; thread them out for the killer
     from repro.comm.transport import SocketServer, spawn_local_workers
     from repro.fl.engine import LiveRoundLoop, RetryPolicy
-    from repro.launch.worker import vision_setup
 
     server = SocketServer(TINY_N, heartbeat_s=run.heartbeat_s,
                           liveness_timeout_s=run.liveness_timeout_s,
@@ -271,8 +266,7 @@ def _tiny_scenarios() -> Dict:
     efs = [None] * TINY_N
     try:
         server.wait_ready(60)
-        server.send_setup(vision_setup(run, model="mlp", spec=spec,
-                                       train_size=TINY_TRAIN))
+        server.send_setup(setup)
         loop = LiveRoundLoop(server, strategy, codec, run, params,
                              on_round=on_round)
         warm = RetryPolicy(max_retries=0, recv_timeout_s=WARM_DEADLINE_S,
@@ -304,9 +298,23 @@ def _tiny_scenarios() -> Dict:
             ef_ok &= efs[i] is None
         else:
             same = efs[i] is not None and bool(
-                (efs[i] == _ravel_row(oracle_ef, i)).all())
+                (efs[i] == oracle_efs[i]).all())
             ef_detail[str(i)] = bool(same)
             ef_ok &= same
+    # the vmapped engine on the same fault pattern runs the clients at
+    # width N: within two roundings of the largest weight of the live run
+    engine = _tiny_oracle(model, params, strategy, codec, fl, train, pools,
+                          plan, part)
+    state, _ = engine.run_loop(engine.init_state(params, TINY_N, strategy),
+                               TINY_ROUNDS)
+    vmap_params, vmap_ef = jax.device_get((state.params, state.ef))
+    vmap_tol = 2 * float(np.spacing(np.max(np.abs(_ravel(vmap_params)))))
+    vmap_diff = float(np.max(np.abs(_ravel(vmap_params)
+                                    - _ravel(live_params))))
+    for i in range(TINY_N):
+        if efs[i] is not None:
+            row = _ravel(jax.tree_util.tree_map(lambda l: l[i], vmap_ef))
+            vmap_diff = max(vmap_diff, float(np.max(np.abs(row - efs[i]))))
     cons = _conservation(engine, model, params, strategy, fl, train, pools)
     # conservation transfers to the wire because the dropped client's EF
     # (CONS_CID survives the run) is bitwise equal to the oracle's
@@ -322,6 +330,9 @@ def _tiny_scenarios() -> Dict:
         "params_bitwise": params_ok,
         "ef_bitwise": ef_detail,
         "ef_all_ok": bool(ef_ok),
+        "vmap_max_abs_diff": vmap_diff,
+        "vmap_tol": vmap_tol,
+        "vmap_within_tol": vmap_diff <= vmap_tol,
         "dead_at_end": sorted(loop.history[-1]["dead"]),
         "retries_per_round": [r["retries"] for r in loop.history],
         "uplink_bytes_per_round": up_per_round,
@@ -441,7 +452,8 @@ def _gate(results: Dict) -> Dict:
                      == mlp["wire_reference"]["threesfc_measured_bytes"])
     results["pass_bytes_match"] = bool(bytes_ok)
     results["pass_socket_bitwise"] = bool(
-        tiny["masks_match"] and tiny["params_bitwise"] and tiny["ef_all_ok"])
+        tiny["masks_match"] and tiny["params_bitwise"] and tiny["ef_all_ok"]
+        and tiny["vmap_within_tol"])
     results["pass_residual_conservation"] = bool(
         tiny["conservation"]["exact"]
         and tiny["conservation"]["wire_ef_bitwise"])
@@ -496,7 +508,8 @@ def run(quick: bool = True, out_dir: str = "experiments/results") -> Dict:
     print(f"  [{'PASS' if results['pass_socket_bitwise'] else 'FAIL'}] "
           f"live faulted run bitwise == oracle: masks "
           f"{t['masks_match']}, params {t['params_bitwise']}, "
-          f"EF {t['ef_bitwise']}")
+          f"EF {t['ef_bitwise']}; vmapped engine max |diff| "
+          f"{t['vmap_max_abs_diff']:.3g} <= {t['vmap_tol']:.3g}")
     print(f"  [{'PASS' if results['pass_residual_conservation'] else 'FAIL'}]"
           f" residual mass conserved on dropped frame (round "
           f"{CONS_ROUND}, cid {CONS_CID}): exact="

@@ -238,7 +238,8 @@ def check_transport(fresh, base, tol):
             ("pass_bytes_match", "wire bills more than N*nbytes (or "
              "diverges from BENCH_wire's measured bytes)"),
             ("pass_socket_bitwise", "live socket round no longer bitwise "
-             "equal to the in-process oracle on the same fault pattern"),
+             "equal to the width-matched oracle on the same fault pattern, "
+             "or off the vmapped engine by more than two roundings"),
             ("pass_residual_conservation", "EF residual mass not conserved "
              "on a dropped frame"),
             ("pass_straggle_isolation", "a straggler's sleep leaked into "

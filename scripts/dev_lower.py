@@ -10,6 +10,7 @@ import jax
 from repro.configs import base
 from repro.configs.base import ARCH_IDS, ShapeConfig, get_smoke_config
 from repro.launch import specs as specs_lib
+from repro.launch.mesh import make_mesh
 from repro.utils import roofline as rl
 
 # shrink the shape matrix + swap in smoke configs
@@ -23,7 +24,7 @@ specs_lib.INPUT_SHAPES = SMALL_SHAPES
 specs_lib.LONG_CTX_WINDOW = 64
 specs_lib.get_config = get_smoke_config
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 
 archs = sys.argv[1:] or ARCH_IDS
 for arch in archs:

@@ -65,6 +65,7 @@ def build_context() -> Dict[str, Any]:
     import jax.numpy as jnp
 
     from repro.fl.sharding import make_fl_shardings
+    from repro.launch.mesh import make_mesh
     from repro.models.cnn import VisionSpec, make_paper_model
 
     spec = VisionSpec("tiny", TINY_INPUT, TINY_CLASSES)
@@ -73,7 +74,7 @@ def build_context() -> Dict[str, Any]:
     mesh = sh = None
     client_shards = 1
     if len(jax.devices()) >= TINY_MESH_SHAPE[0]:
-        mesh = jax.make_mesh(TINY_MESH_SHAPE, ("data", "model"))
+        mesh = make_mesh(TINY_MESH_SHAPE, ("data", "model"))
         sh = make_fl_shardings(mesh)
         client_shards = sh.client_shards
     batches = {

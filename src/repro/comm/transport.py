@@ -722,14 +722,16 @@ def spawn_local_workers(address: Tuple[str, int],
     ``address``. Local spawning is a convenience — the workers themselves
     only know a ``host:port``, so running them on other hosts is a config
     change. The child env gets ``src/`` on PYTHONPATH (derived from this
-    package's location) and defaults to the CPU backend for determinism."""
+    package's location) and always runs on the CPU backend."""
     host, port = address
     src_root = os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
     e = dict(os.environ if env is None else env)
     old = e.get("PYTHONPATH")
     e["PYTHONPATH"] = src_root + ((os.pathsep + old) if old else "")
-    e.setdefault("JAX_PLATFORMS", "cpu")
+    # the server process owns the accelerator; a worker that inherited a
+    # TPU platform would wait on (or fail for) the chip's lock
+    e["JAX_PLATFORMS"] = "cpu"
     return [subprocess.Popen(
         [sys.executable, "-m", "repro.launch.worker",
          "--connect", f"{host}:{port}", "--client-id", str(cid)], env=e)

@@ -91,7 +91,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.fl.round import FLState, RoundMetrics, fl_init
-from repro.fl.server import server_update
+from repro.fl.server import client_mean, server_update
 from repro.obs import get_registry, get_tracer
 
 PyTree = Any
@@ -477,6 +477,29 @@ class RoundEngine:
         return state, metrics
 
 
+def live_server_step(codec, num_clients: int, server_lr: float):
+    """The live loop's jitted server step ``(params, (N, nbytes) uint8
+    frames, (N,) delivered) -> params``: a bitwise mirror of ``fl.round``'s
+    faulted codec path at S=0, weights=None (vmap decode -> recon ->
+    mean(where) * N/count -> ``server_update``)."""
+    N = num_clients
+
+    def step(p, bufs, delivered):
+        canon = jax.vmap(codec.decode)(bufs)
+        recons = jax.vmap(lambda c: codec.recon_tree(c, p))(canon)
+        cnt = jnp.sum(delivered.astype(jnp.float32))
+        ratio = jnp.where(cnt > 0, N / cnt, 0.0)
+        agg = jax.tree_util.tree_map(
+            lambda m: m * ratio,
+            client_mean(jax.tree_util.tree_map(
+                lambda x: jnp.where(
+                    delivered.reshape((-1,) + (1,) * (x.ndim - 1)), x, 0),
+                recons)))
+        return server_update(p, agg, server_lr)
+
+    return jax.jit(step)
+
+
 class LiveRoundLoop:
     """The server half of a live cross-process round over a transport.
 
@@ -527,21 +550,7 @@ class LiveRoundLoop:
         self._enc = jax.jit(
             lambda p, r: self._down.encode(p, round_idx=r))
 
-        def step(p, bufs, delivered):
-            # bitwise mirror of fl.round's faulted codec path at S=0,
-            # weights=None: vmap decode -> recon -> mean(where) * N/count
-            canon = jax.vmap(codec.decode)(bufs)
-            recons = jax.vmap(lambda c: codec.recon_tree(c, p))(canon)
-            cnt = jnp.sum(delivered.astype(jnp.float32))
-            ratio = jnp.where(cnt > 0, N / cnt, 0.0)
-            agg = jax.tree_util.tree_map(
-                lambda x: jnp.mean(
-                    jnp.where(delivered.reshape((-1,) + (1,) * (x.ndim - 1)),
-                              x, 0), axis=0) * ratio,
-                recons)
-            return server_update(p, agg, server_lr)
-
-        self._step = jax.jit(step)
+        self._step = live_server_step(codec, N, server_lr)
         self._placeholder = np.zeros((codec.nbytes,), np.uint8)
 
     def run(self, num_rounds: int, *, deadline_s: Optional[float] = None,

@@ -63,7 +63,6 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import FLConfig
@@ -72,7 +71,8 @@ from repro.core import flat
 from repro.core.strategy import CompressionStrategy, warn_deprecated_once
 from repro.fl import faults as faults_lib
 from repro.fl.client import local_train
-from repro.fl.server import aggregate, server_update
+from repro.fl.server import (aggregate, client_mean, client_sum,
+                              server_update)
 
 PyTree = Any
 
@@ -258,12 +258,12 @@ def build_fl_round(
                 o if i == 1 else jax.tree_util.tree_map(gather, o)
                 for i, o in enumerate(outs))
 
-        fanout = shard_map(
+        fanout = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(axes), P(axes), P(axes), P(axes), P())
             + (P(axes),) * n_extra,
             out_specs=tuple(P(axes) if i == 1 else P() for i in range(4)),
-            check_rep=False,
+            check_vma=False,
         )
 
     def _replicate(x):
@@ -310,9 +310,9 @@ def build_fl_round(
             cnt = jnp.sum(now.astype(jnp.float32))
             ratio = jnp.where(cnt > 0, N / cnt, 0.0)
             agg = jax.tree_util.tree_map(
-                lambda x: jnp.mean(jnp.where(_mask_bcast(now, x), x, 0),
-                                   axis=0) * ratio,
-                recons)
+                lambda m: m * ratio,
+                client_mean(jax.tree_util.tree_map(
+                    lambda x: jnp.where(_mask_bcast(now, x), x, 0), recons)))
             return agg, cnt, state.buf, state.buf_w
         # generic path: staleness-weighted sum of fresh + matured payloads,
         # renormalized by the total arrived weight
@@ -320,8 +320,8 @@ def build_fl_round(
         w_now = jnp.where(now, sched.weight * base_w, 0.0)
         if S == 0:
             mature_w = jnp.float32(0.0)
-            num = jax.tree_util.tree_map(
-                lambda x: jnp.sum(_mask_bcast(w_now, x) * x, axis=0), recons)
+            num = client_sum(jax.tree_util.tree_map(
+                lambda x: _mask_bcast(w_now, x) * x, recons))
             buf, buf_w = state.buf, state.buf_w
         else:
             if state.buf_w is None:
@@ -334,8 +334,8 @@ def build_fl_round(
                 state.buf, state.buf_w, state.round, sched.delay, w_late,
                 recons)
             num = jax.tree_util.tree_map(
-                lambda x, m: jnp.sum(_mask_bcast(w_now, x) * x, axis=0) + m,
-                recons, mature)
+                jnp.add, client_sum(jax.tree_util.tree_map(
+                    lambda x: _mask_bcast(w_now, x) * x, recons)), mature)
         den = jnp.sum(w_now) + mature_w
         inv = jnp.where(den > 0, 1.0 / den, 0.0)
         agg = jax.tree_util.tree_map(lambda x: x * inv, num)
@@ -366,10 +366,10 @@ def build_fl_round(
             # loss over participants only (mean × N/count: exact 1.0 when
             # everyone participates, same identity as the aggregate)
             cnt_p = jnp.sum(sched.participate.astype(jnp.float32))
-            loss = jnp.mean(jnp.where(sched.participate, losses, 0.0)) * \
+            loss = client_mean(jnp.where(sched.participate, losses, 0.0)) * \
                 jnp.where(cnt_p > 0, N / cnt_p, 0.0)
         else:
-            loss = jnp.mean(losses)
+            loss = client_mean(losses)
         if fused:
             if axes is None:
                 # vmap fan-out: the payloads are tiny -> pin replicated
@@ -408,7 +408,8 @@ def build_fl_round(
                           jnp.mean(metrics.payload_floats), arrivals,
                           buf, buf_w)
         # inputs are full (N, ...) arrays in client order on both fan-out
-        # paths, so the reduction order — hence the result — is identical
+        # paths, and aggregate adds them in that order (server.client_sum),
+        # so the result is identical
         agg = aggregate(recons, weights)
         return finish(state, agg, ef_new, loss, metrics,
                       jnp.mean(metrics.payload_floats),

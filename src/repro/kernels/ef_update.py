@@ -24,7 +24,7 @@ def _kernel(u_ref, d_ref, s_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def ef_update_2d(u2: jax.Array, d2: jax.Array, s: jax.Array, *,
-                 block_rows: int = BLOCK_ROWS, interpret: bool = True) -> jax.Array:
+                 block_rows: int = BLOCK_ROWS, interpret: bool) -> jax.Array:
     rows = u2.shape[0]
     assert rows % block_rows == 0 and u2.shape == d2.shape
     s2 = jnp.reshape(s.astype(jnp.float32), (1, 1))

@@ -8,9 +8,11 @@ computes all three partial sums per VMEM tile in a single pass.
 
 Tiling: inputs are padded/reshaped to (rows, 1024) lanes (8·128-aligned);
 each grid step streams a (BLOCK_ROWS, 1024) tile of x and y through VMEM
-(2 × 512 KB) and accumulates into a (1, 3) f32 accumulator that lives in the
-output block (same block every step — the TPU grid is sequential, so this is
-the standard Pallas reduction idiom).
+(2 × 512 KB) and folds each product tile into one (8, 128) vreg of partial
+sums per statistic — elementwise adds only, no cross-lane reduction and no
+scalar store. The (3, 8, 128) accumulator lives in the output block (same
+block every step — the TPU grid is sequential, so this is the standard
+Pallas reduction idiom); the wrapper sums its 1024 partials per statistic.
 
 HBM-pass accounting
 -------------------
@@ -36,6 +38,17 @@ from jax.experimental import pallas as pl
 
 LANES = 1024
 BLOCK_ROWS = 128
+ACC_SHAPE = (3, 8, 128)   # one f32 vreg of partial sums per statistic
+ACC_BYTES = 3 * 8 * 128 * 4
+
+
+def _fold(v: jax.Array) -> jax.Array:
+    """(rows, LANES) -> (8, 128) partial sums, by vreg-aligned adds."""
+    v = jnp.sum(v.reshape(-1, 8, LANES), axis=0)
+    acc = v[:, :128]
+    for j in range(1, LANES // 128):
+        acc = acc + v[:, j * 128:(j + 1) * 128]
+    return acc
 
 
 def _kernel(x_ref, y_ref, o_ref):
@@ -45,14 +58,14 @@ def _kernel(x_ref, y_ref, o_ref):
 
     x = x_ref[...].astype(jnp.float32)
     y = y_ref[...].astype(jnp.float32)
-    o_ref[0, 0] += jnp.sum(x * y)
-    o_ref[0, 1] += jnp.sum(x * x)
-    o_ref[0, 2] += jnp.sum(y * y)
+    o_ref[0] += _fold(x * y)
+    o_ref[1] += _fold(x * x)
+    o_ref[2] += _fold(y * y)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def fused_cosine_2d(x2: jax.Array, y2: jax.Array, *, block_rows: int = BLOCK_ROWS,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """x2, y2: (rows, LANES) with rows % block_rows == 0. Returns (3,) f32."""
     rows = x2.shape[0]
     assert rows % block_rows == 0 and x2.shape == y2.shape
@@ -64,8 +77,8 @@ def fused_cosine_2d(x2: jax.Array, y2: jax.Array, *, block_rows: int = BLOCK_ROW
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 3), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 3), jnp.float32),
+        out_specs=pl.BlockSpec(ACC_SHAPE, lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(ACC_SHAPE, jnp.float32),
         interpret=interpret,
     )(x2, y2)
-    return out[0]
+    return jnp.sum(out, axis=(1, 2))

@@ -1,9 +1,11 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Each wrapper handles flattening/padding to the (rows, 1024)-lane layout the
-kernels tile over, dispatches interpret mode off-TPU, and reduces kernel
-partials to the user-facing result. ``on_tpu()`` flips interpret mode
-automatically, so the same call sites run compiled on real hardware.
+kernels tile over and reduces kernel partials to the user-facing result.
+``_interpret()`` is the one switch that picks the kernels' mode: compiled
+on a TPU backend, the Pallas interpreter on any other. The kernel
+signatures take ``interpret`` without a default, so every caller goes
+through this switch or states its mode.
 
 HBM-pass accounting
 -------------------
@@ -36,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.ef_update import ef_update_2d
-from repro.kernels.fused_cosine import fused_cosine_2d
+from repro.kernels.fused_cosine import ACC_BYTES, fused_cosine_2d
 from repro.kernels.sign_quant import sign_quant_2d
 from repro.kernels.ssd_chunk import ssd_chunk_call
 from repro.kernels.topk_mask import topk_mask_2d
@@ -51,12 +53,8 @@ LANES = 1024
 TREE_CHUNK_ELEMS = 1 << 22
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _interpret() -> bool:
-    return not on_tpu()
+    return jax.default_backend() != "tpu"
 
 
 def _plan_rows(n: int, block_rows: int) -> Tuple[int, int]:
@@ -221,7 +219,8 @@ def tree_stats_hbm_bytes(tree: PyTree, block_rows: int = 128) -> int:
     """Static HBM bytes ``tree_fused_stats`` touches for this tree pair.
 
     Not a measurement: the Pallas grid DMAs exactly two (block_rows, LANES)
-    f32 tiles per step plus the (1, 3) accumulator — the traffic is fixed by
+    f32 tiles per step plus one (3, 8, 128) f32 accumulator write per call
+    (``fused_cosine.ACC_BYTES``) — the traffic is fixed by
     the BlockSpecs, so it can be accounted from the chunk plan alone. Used
     by ``benchmarks/bench_kernels.py``; XLA ``cost_analysis`` cannot see
     through the interpret-mode callback, and on CPU it charges every
@@ -233,7 +232,7 @@ def tree_stats_hbm_bytes(tree: PyTree, block_rows: int = 128) -> int:
     for chunk in _chunk_plan(sizes, TREE_CHUNK_ELEMS):
         n = sum(take for _, _, take in chunk)
         _, rows = _plan_rows(n, block_rows)
-        total += 2 * rows * LANES * 4 + 3 * 4   # two operand tiles + (1,3) acc
+        total += 2 * rows * LANES * 4 + ACC_BYTES   # two operand tiles + acc
     return total
 
 

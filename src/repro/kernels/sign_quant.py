@@ -29,7 +29,7 @@ def _kernel(x_ref, sign_ref, acc_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def sign_quant_2d(x2: jax.Array, *, block_rows: int = BLOCK_ROWS,
-                  interpret: bool = True):
+                  interpret: bool):
     """Returns (signs int8 (rows, LANES), sum|x| (1,1) f32)."""
     rows = x2.shape[0]
     assert rows % block_rows == 0

@@ -46,7 +46,7 @@ def _kernel(x_ref, dA_ref, B_ref, C_ref, y_ref, st_ref, dec_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ssd_chunk_call(xdt: jax.Array, dA: jax.Array, B: jax.Array, C: jax.Array,
-                   *, interpret: bool = True):
+                   *, interpret: bool):
     """xdt (b,h,nc,Q,P);  dA (b,h,nc,Q);  B,C (b,nc,Q,N).
 
     Returns (y_diag (b,h,nc,Q,P), states (b,h,nc,P,N), decay (b,h,nc,Q)).

@@ -31,7 +31,7 @@ def _kernel(x_ref, t_ref, out_ref, cnt_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def topk_mask_2d(x2: jax.Array, threshold: jax.Array, *,
-                 block_rows: int = BLOCK_ROWS, interpret: bool = True):
+                 block_rows: int = BLOCK_ROWS, interpret: bool):
     rows = x2.shape[0]
     assert rows % block_rows == 0
     t2 = jnp.reshape(threshold.astype(jnp.float32), (1, 1))

@@ -27,7 +27,7 @@ import jax
 
 from repro.configs.base import ARCH_IDS, INPUT_SHAPES, get_config
 from repro.launch import specs as specs_lib
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.utils import roofline as rl
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
@@ -48,7 +48,7 @@ def run_pair(arch: str, shape_name: str, multi_pod: bool = False,
              variant: Optional[dict] = None, tag: str = "",
              mesh_shape: Optional[tuple] = None) -> Optional[dict]:
     if mesh_shape:                      # §Perf mesh reshape (e.g. (4, 64))
-        mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+        mesh = make_mesh(mesh_shape, ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     chips = mesh.devices.size

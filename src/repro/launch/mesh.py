@@ -6,14 +6,22 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Tuple[int, ...],
+              axes: Tuple[str, ...]) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: the FL round's sharding
+    constraints and ``shard_map`` specs are written for GSPMD-partitioned
+    axes, and ``jax.make_mesh`` defaults to ``Explicit`` ones."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """TPU v5e pod slice: 16x16 = 256 chips per pod; 2 pods = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
@@ -24,7 +32,7 @@ def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
             f"make_host_mesh: {n} device(s) cannot be split into a "
             f"(data={n}//{model}, model={model}) mesh — n % model must be 0 "
             f"(a truncated mesh would silently drop devices)")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def client_axes(mesh: jax.sharding.Mesh) -> Tuple[str, ...]:

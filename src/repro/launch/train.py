@@ -48,6 +48,7 @@ from repro.fl.engine import (RetryPolicy, RoundEngine, device_pools,
                              token_batcher, vision_batcher)
 from repro.fl.round import build_fl_round
 from repro.fl.sharding import make_fl_shardings
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.build import build_model, syn_loss_fn, syn_spec_for, vision_syn_spec
 from repro.models.cnn import DATASETS, accuracy, make_paper_model
@@ -402,7 +403,7 @@ def train_vision(args):
                    "acc": float(eval_acc(st.params)),
                    "cos": float(np.mean(m.cosine[-1])),
                    "payload_floats": float(m.payload_floats[-1]),
-                   "elapsed_s": round(time.time() - t0, 1)}
+                   "elapsed_s": time.time() - t0}
             print(json.dumps(rec))
             log.write(json.dumps(rec) + "\n")
             log.flush()
@@ -577,6 +578,7 @@ def main(argv=None):
                          "snapshot) on this port for the run's duration "
                          "(0 picks a free port)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.trace:
         configure_tracer(True, proc="server")
     http = None

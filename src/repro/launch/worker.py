@@ -71,6 +71,7 @@ from repro.comm.transport import (FLAG_PARTICIPATE, MSG_ACK, MSG_EF_DUMP,
                                   MSG_EF_PUSH, MSG_EF_REQ, MSG_EF_SYNC,
                                   MSG_FRAME, MSG_METRIC, MSG_RESEND,
                                   MSG_ROUND, MSG_SETUP, MSG_STOP, ServerLink)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import configure_tracer, get_logger, get_tracer
 
 PyTree = Any
@@ -254,6 +255,40 @@ def build_compute(setup: Dict, client_id: int):
     return VisionClientCompute(setup, client_id)
 
 
+def replay_live_run(setup: Dict, params: PyTree, delivered: np.ndarray,
+                    participate: Optional[np.ndarray] = None):
+    """A live socket run's own computation, in one process: the bitwise
+    oracle for ``LiveRoundLoop`` over ``setup``'s workers.
+
+    Per round, each participating client runs its worker step
+    (``build_compute``, width 1, as its worker runs it) and commits by its
+    frame's fate; the delivered frames then go through the live loop's
+    server step (``fl.engine.live_server_step``). ``delivered`` and
+    ``participate`` (default: everyone) are (rounds, N) bool masks.
+    Returns ``(params, [flat f32 EF per client])``."""
+    from repro.configs.run import RunConfig
+    from repro.fl.engine import live_server_step
+
+    rounds, n = delivered.shape
+    participate = np.ones_like(delivered) if participate is None \
+        else participate
+    clients = [build_compute(setup, i) for i in range(n)]
+    codec = clients[0].codec
+    step = live_server_step(codec, n,
+                            RunConfig.from_json(setup["run"]).fl.server_lr)
+    for r in range(rounds):
+        bufs = np.zeros((n, codec.nbytes), np.uint8)
+        for i, c in enumerate(clients):
+            if participate[r, i]:
+                frame = np.frombuffer(c.compute(params, r)[0], np.uint8)
+                if delivered[r, i]:
+                    bufs[i] = frame
+                c.commit(bool(delivered[r, i]))
+        params = step(params, jnp.asarray(bufs), jnp.asarray(delivered[r]))
+    return jax.device_get(params), [
+        np.frombuffer(c.ef_bytes(), np.float32) for c in clients]
+
+
 def _serve(link: ServerLink, compute, client_id: int,
            straggle_s: float, log=None) -> None:
     """The worker's message loop: ROUND -> compute/frame/metric, RESEND ->
@@ -382,6 +417,7 @@ def main(argv=None):
     ap.add_argument("--connect", required=True, metavar="HOST:PORT")
     ap.add_argument("--client-id", type=int, required=True, dest="client_id")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     host, port = args.connect.rsplit(":", 1)
     run_worker((host, int(port)), args.client_id)
 

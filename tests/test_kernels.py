@@ -85,7 +85,7 @@ def test_ssd_single_chunk_kernel_vs_ref():
     dA = -0.3 * jax.nn.softplus(jax.random.normal(jax.random.PRNGKey(1), (1, 1, 1, Q)))
     B = jax.random.normal(jax.random.PRNGKey(2), (1, 1, Q, N))
     C = jax.random.normal(jax.random.PRNGKey(3), (1, 1, Q, N))
-    y, st, dec = ssd_chunk_call(x, dA, B, C)
+    y, st, dec = ssd_chunk_call(x, dA, B, C, interpret=ops._interpret())
     ry, rst, rdec = ref.ssd_chunk(x[0, 0, 0], dA[0, 0, 0], B[0, 0], C[0, 0])
     np.testing.assert_allclose(y[0, 0, 0], ry, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(st[0, 0, 0], rst, rtol=1e-5, atol=1e-6)

@@ -15,6 +15,9 @@ whose per-client math differentiates the model (3SFC) are bitwise only on a
 width-matched mesh (client axis 1); fedavg/dgc/signsgd/stc are bitwise on
 the real 8-way client axis.
 """
+from repro.launch.mesh import make_mesh
+
+
 def test_shard_map_bitexact_vs_vmap_all_compressors(multidev_scenario):
     """3 scanned rounds on the 8-way client mesh: bitwise params/EF/metrics
     for the width-stable compressors; 3SFC bitwise width-matched + tight
@@ -120,9 +123,9 @@ def scenario_bitexact():
 
     from repro.fl.sharding import make_fl_shardings
 
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     sh = make_fl_shardings(mesh)
-    mesh_w = jax.make_mesh((1, 8), ("data", "model"))   # width-matched
+    mesh_w = make_mesh((1, 8), ("data", "model"))   # width-matched
     sh_w = make_fl_shardings(mesh_w)
     _, engine, CompressorConfig = _world()
 
@@ -205,7 +208,7 @@ def scenario_ef_donation():
 
     from repro.fl.sharding import make_fl_shardings
 
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     sh = make_fl_shardings(mesh)
     params, engine, CompressorConfig = _world()
     eng, state = engine(CompressorConfig(kind="identity",
@@ -297,7 +300,7 @@ def scenario_wire():
 
     from repro.fl.sharding import make_fl_shardings
 
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     sh = make_fl_shardings(mesh)
     _, engine, CompressorConfig = _world()
 
@@ -366,7 +369,7 @@ def scenario_faults():
     from repro.models.build import vision_syn_spec
     from repro.models.cnn import VisionSpec, make_paper_model
 
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     sh = make_fl_shardings(mesh)
     N, K, B = 8, 1, 8
     SPEC = VisionSpec("tiny", (4, 4, 1), 3)

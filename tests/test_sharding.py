@@ -11,7 +11,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ShapeConfig, get_smoke_config
 from repro.launch import specs as specs_lib
-from repro.launch.mesh import client_axes, num_clients_for
+from repro.launch.mesh import client_axes, make_mesh, num_clients_for
 from repro.models import params as params_lib
 from repro.models.build import build_model
 
@@ -25,7 +25,7 @@ needs_multidev = pytest.mark.skipif(
 def _mesh():
     n = len(jax.devices())
     m = 2 if n % 2 == 0 else 1
-    return jax.make_mesh((n // m, m), ("data", "model"))
+    return make_mesh((n // m, m), ("data", "model"))
 
 
 @needs_multidev

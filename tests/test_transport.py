@@ -232,9 +232,13 @@ def test_stale_frame_is_billed_then_discarded():
 def test_live_socket_round_bitwise_equals_inprocess_oracle():
     """Two real worker subprocesses drive a round over the socket; params,
     per-client EF, and per-round billing must be bitwise what the
-    in-process vmapped oracle computes from the same seed."""
+    width-matched in-process oracle computes from the same seed: each
+    client's step at width 1 (as its worker runs it) and the live loop's
+    server step. The vmapped in-process engine, which runs both clients
+    at width 2, agrees to two roundings of the largest weight (XLA lowers
+    the client step per vmap width; observed 9.3e-10 on 1 of 48,203
+    params)."""
     import jax
-    import jax.numpy as jnp
 
     from repro.comm.transport import spawn_local_workers
     from repro.configs.base import CompressorConfig, FLConfig
@@ -246,7 +250,7 @@ def test_live_socket_round_bitwise_equals_inprocess_oracle():
                                  vision_batcher)
     from repro.fl.faults import null_schedule
     from repro.fl.round import build_fl_round
-    from repro.launch.worker import vision_setup
+    from repro.launch.worker import replay_live_run, vision_setup
     from repro.models.build import vision_syn_spec
     from repro.models.cnn import VisionSpec, make_paper_model
 
@@ -280,15 +284,19 @@ def test_live_socket_round_bitwise_equals_inprocess_oracle():
         seed=fl.seed)
     state = engine.init_state(params, N, strategy)
     state, _ = engine.run_loop(state, R)
-    oracle_params, oracle_ef = jax.device_get((state.params, state.ef))
+    vmap_params, vmap_ef = jax.device_get((state.params, state.ef))
+
+    # width-matched oracle: the workers' own client computation in-process
+    setup = vision_setup(run, model="mlp", spec=spec, train_size=train_n)
+    oracle_params, oracle_efs = replay_live_run(
+        setup, params, np.ones((R, N), bool))
 
     server = SocketServer(N, heartbeat_s=run.heartbeat_s,
                           liveness_timeout_s=run.liveness_timeout_s)
     procs = spawn_local_workers(server.address, range(N))
     try:
         server.wait_ready(60)
-        server.send_setup(vision_setup(run, model="mlp", spec=spec,
-                                       train_size=train_n))
+        server.send_setup(setup)
         loop = LiveRoundLoop(server, strategy, codec, run, params)
         # round 0 compiles inside the workers: generous window, no resends
         warm = RetryPolicy(max_retries=0, recv_timeout_s=240.0,
@@ -308,13 +316,18 @@ def test_live_socket_round_bitwise_equals_inprocess_oracle():
         return np.concatenate([np.asarray(l, np.float32).ravel()
                                for l in jax.tree_util.tree_leaves(t)])
 
+    # against the vmapped engine: two roundings of the largest weight
+    tol = 2 * float(np.spacing(np.max(np.abs(ravel(vmap_params)))))
     assert all(rec["delivered"].all() for rec in loop.history)
     np.testing.assert_array_equal(ravel(oracle_params), ravel(live_params))
+    np.testing.assert_allclose(ravel(vmap_params), ravel(live_params),
+                               rtol=0, atol=tol)
     for i in range(N):
-        oe = np.concatenate([np.asarray(l[i], np.float32).ravel()
-                             for l in jax.tree_util.tree_leaves(oracle_ef)])
         assert efs[i] is not None
-        np.testing.assert_array_equal(efs[i], oe)
+        np.testing.assert_array_equal(efs[i], oracle_efs[i])
+        ve = np.concatenate([np.asarray(l[i], np.float32).ravel()
+                             for l in jax.tree_util.tree_leaves(vmap_ef)])
+        np.testing.assert_allclose(efs[i], ve, rtol=0, atol=tol)
     # the settled round billed exactly the codec bytes — headers, ACKs and
     # heartbeats live in the overhead buckets, not the data-plane stats
     assert loop.history[1]["bytes_up"] == N * codec.nbytes

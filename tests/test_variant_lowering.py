@@ -8,6 +8,7 @@ import pytest
 
 from repro.configs.base import ShapeConfig, get_smoke_config
 from repro.launch import specs as specs_lib
+from repro.launch.mesh import make_mesh
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 2, reason="needs >=2 devices (see dryrun flags)")
@@ -28,7 +29,7 @@ def _small(monkeypatch):
 
 def _mesh():
     n = len(jax.devices())
-    return jax.make_mesh((n // 2, 2), ("data", "model"))
+    return make_mesh((n // 2, 2), ("data", "model"))
 
 
 @pytest.mark.parametrize("variant", [
