@@ -90,7 +90,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.fl.round import FLState, RoundMetrics, fl_init
+from repro.fl.round import BATCH_SCOPE, FLState, RoundMetrics, fl_init
 from repro.fl.server import client_mean, server_update
 from repro.obs import get_registry, get_tracer
 
@@ -350,9 +350,10 @@ class RoundEngine:
 
     # -- the round body (shared by scan and reference loop) ----------------
     def _round(self, state: FLState) -> Tuple[FLState, RoundMetrics]:
-        batches = self._batch_fn(self._data_key, state.round)
-        if self.shardings is not None:
-            batches = self.shardings.constrain_client_tree(batches)
+        with jax.named_scope(BATCH_SCOPE):
+            batches = self._batch_fn(self._data_key, state.round)
+            if self.shardings is not None:
+                batches = self.shardings.constrain_client_tree(batches)
         key = jax.random.fold_in(self._round_key, state.round)
         return self._round_fn(state, batches, key)
 
@@ -376,6 +377,14 @@ class RoundEngine:
                                    self.shardings.replicated))
             self._blocks[length] = fn
         return fn
+
+    def block_hlo_text(self, state: FLState, length: int) -> str:
+        """Optimized HLO text of the ``length``-round block executable, with
+        its op_name metadata: the instruction names a profiler trace shows,
+        each under the phase scopes (``fl.round.PHASE_SCOPES``) it came
+        from. Compiles, or loads from the compile cache, and runs nothing;
+        ``state`` is only read for its shapes and is not consumed."""
+        return self._block(length).lower(state).compile().as_text()
 
     # -- scanned path ------------------------------------------------------
     def run_block(self, state: FLState,

@@ -81,6 +81,19 @@ PyTree = Any
 # the region stays collective-free (tested in tests/test_hlo_analyzer.py).
 CLIENT_SCOPE = "fl_client_local"
 
+# Named scopes of the round's phases, the same in both fan-outs. They add
+# op_name metadata only (the compiled instructions are unchanged), so a
+# profiler trace of the round can be split by phase (bench/devtrace.py).
+# BATCH_SCOPE wraps the engine's batch gather (fl/engine.py).
+BATCH_SCOPE = "fl.batch"
+LOCAL_TRAIN_SCOPE = "fl.local_train"
+ENCODE_SCOPE = "fl.encode"          # strategy step, kernels, codec pack
+GATHER_SCOPE = "fl.gather"          # the shard_map fan-out's all_gather
+AGGREGATE_SCOPE = "fl.aggregate"    # decode, client sums, server_aggregate
+UPDATE_SCOPE = "fl.update"          # server_update, EF cast, RoundMetrics
+PHASE_SCOPES = (BATCH_SCOPE, LOCAL_TRAIN_SCOPE, ENCODE_SCOPE, GATHER_SCOPE,
+                AGGREGATE_SCOPE, UPDATE_SCOPE)
+
 
 class FLState(NamedTuple):
     params: PyTree          # global model w^t
@@ -202,10 +215,12 @@ def build_fl_round(
             return strategy.step(key_i, g, ef_i, params)
 
     def client_core(global_params, ef_i, batches_i, key_i, cid, rnd):
-        g, loss = local_train(loss_fn, global_params, batches_i,
-                              cfg.local_lr, num_micro=run.num_micro)
-        msg, ef_new, metrics = encode(key_i, g, ef_i, global_params,
-                                      cid, rnd)
+        with jax.named_scope(LOCAL_TRAIN_SCOPE):
+            g, loss = local_train(loss_fn, global_params, batches_i,
+                                  cfg.local_lr, num_micro=run.num_micro)
+        with jax.named_scope(ENCODE_SCOPE):
+            msg, ef_new, metrics = encode(key_i, g, ef_i, global_params,
+                                          cid, rnd)
         return g, msg, ef_new, loss, metrics
 
     if not faulted:
@@ -254,9 +269,10 @@ def build_fl_round(
             # fault masks ride IN as client-sharded scalars (per-client
             # where-selects in the scope above), never adding a collective.
             gather = lambda x: jax.lax.all_gather(x, axes, tiled=True)
-            return tuple(
-                o if i == 1 else jax.tree_util.tree_map(gather, o)
-                for i, o in enumerate(outs))
+            with jax.named_scope(GATHER_SCOPE):
+                return tuple(
+                    o if i == 1 else jax.tree_util.tree_map(gather, o)
+                    for i, o in enumerate(outs))
 
         fanout = jax.shard_map(
             body, mesh=mesh,
@@ -341,27 +357,9 @@ def build_fl_round(
         agg = jax.tree_util.tree_map(lambda x: x * inv, num)
         return agg, den, buf, buf_w
 
-    def fl_round(state: FLState, client_batches: PyTree, key: jax.Array,
-                 weights: jax.Array = None):
-        keys = jax.random.split(key, cfg.num_clients)
-        cids = jnp.arange(cfg.num_clients, dtype=jnp.uint32)
-        if faulted:
-            if fault_schedule_fn is not None:
-                sched = fault_schedule_fn(state.round, N)
-            else:
-                sched = faults_lib.fault_schedule(
-                    fault_key, state.round, N,
-                    participation_rate=run.participation_rate,
-                    drop_rate=run.drop_rate,
-                    straggler_rate=run.straggler_rate,
-                    staleness_max=S)
-            extra = (sched.participate, sched.delivered)
-        else:
-            sched = None
-            extra = ()
-        msgs, ef_new, losses, metrics = fanout(
-            state.params, state.ef, client_batches, keys, cids, state.round,
-            *extra)
+    def server_phase(state: FLState, msgs, losses, metrics, sched, weights):
+        """Decode and aggregate the gathered messages; returns ``(loss, agg,
+        payload_floats, arrivals, buf, buf_w)`` for ``finish``."""
         if faulted:
             # loss over participants only (mean × N/count: exact 1.0 when
             # everyone participates, same identity as the aggregate)
@@ -389,11 +387,9 @@ def build_fl_round(
                 cnt = jnp.sum(w)
                 agg = flat.tree_scale(
                     agg, jnp.where(cnt > 0, N / cnt, 0.0))
-                return finish(state, agg, ef_new, loss, metrics, pf, cnt,
-                              state.buf, state.buf_w)
+                return loss, agg, pf, cnt, state.buf, state.buf_w
             agg = strategy.server_aggregate(state.params, payloads)
-            return finish(state, agg, ef_new, loss, metrics, pf,
-                          jnp.float32(N), state.buf, state.buf_w)
+            return loss, agg, pf, jnp.float32(N), state.buf, state.buf_w
         if run.wire == "codec":
             # (N, nbytes) uint8 -> per-client reconstruction trees
             canon = jax.vmap(codec.decode)(msgs)
@@ -404,16 +400,42 @@ def build_fl_round(
         if faulted:
             agg, arrivals, buf, buf_w = _faulted_aggregate(
                 state, recons, sched, weights)
-            return finish(state, agg, ef_new, loss, metrics,
-                          jnp.mean(metrics.payload_floats), arrivals,
-                          buf, buf_w)
+            return (loss, agg, jnp.mean(metrics.payload_floats), arrivals,
+                    buf, buf_w)
         # inputs are full (N, ...) arrays in client order on both fan-out
         # paths, and aggregate adds them in that order (server.client_sum),
         # so the result is identical
         agg = aggregate(recons, weights)
-        return finish(state, agg, ef_new, loss, metrics,
-                      jnp.mean(metrics.payload_floats),
-                      jnp.float32(N), state.buf, state.buf_w)
+        return (loss, agg, jnp.mean(metrics.payload_floats), jnp.float32(N),
+                state.buf, state.buf_w)
+
+    def fl_round(state: FLState, client_batches: PyTree, key: jax.Array,
+                 weights: jax.Array = None):
+        keys = jax.random.split(key, cfg.num_clients)
+        cids = jnp.arange(cfg.num_clients, dtype=jnp.uint32)
+        if faulted:
+            if fault_schedule_fn is not None:
+                sched = fault_schedule_fn(state.round, N)
+            else:
+                sched = faults_lib.fault_schedule(
+                    fault_key, state.round, N,
+                    participation_rate=run.participation_rate,
+                    drop_rate=run.drop_rate,
+                    straggler_rate=run.straggler_rate,
+                    staleness_max=S)
+            extra = (sched.participate, sched.delivered)
+        else:
+            sched = None
+            extra = ()
+        msgs, ef_new, losses, metrics = fanout(
+            state.params, state.ef, client_batches, keys, cids, state.round,
+            *extra)
+        with jax.named_scope(AGGREGATE_SCOPE):
+            loss, agg, pf, arrivals, buf, buf_w = server_phase(
+                state, msgs, losses, metrics, sched, weights)
+        with jax.named_scope(UPDATE_SCOPE):
+            return finish(state, agg, ef_new, loss, metrics, pf, arrivals,
+                          buf, buf_w)
 
     return fl_round
 

@@ -65,6 +65,7 @@ def pack_signs_2d(x2: jax.Array, *, interpret: bool) -> jax.Array:
     x3 = x2.reshape(rows, WORD_LANES, 32).transpose(2, 0, 1)   # bit-major
     words = pl.pallas_call(
         _pack_kernel,
+        name="pack_signs",
         grid=(rows // BLOCK_ROWS,),
         in_specs=[pl.BlockSpec((32, BLOCK_ROWS, WORD_LANES),
                                lambda i: (0, i, 0))],
@@ -82,6 +83,7 @@ def unpack_signs_2d(w2: jax.Array, *, interpret: bool) -> jax.Array:
     assert rows % BLOCK_ROWS == 0 and w2.shape[1] == WORD_LANES, w2.shape
     pm1 = pl.pallas_call(
         _unpack_kernel,
+        name="unpack_signs",
         grid=(rows // BLOCK_ROWS,),
         in_specs=[pl.BlockSpec((BLOCK_ROWS, WORD_LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((32, BLOCK_ROWS, WORD_LANES),

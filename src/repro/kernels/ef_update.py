@@ -30,6 +30,7 @@ def ef_update_2d(u2: jax.Array, d2: jax.Array, s: jax.Array, *,
     s2 = jnp.reshape(s.astype(jnp.float32), (1, 1))
     return pl.pallas_call(
         _kernel,
+        name="ef_update",
         grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),

@@ -72,6 +72,7 @@ def fused_cosine_2d(x2: jax.Array, y2: jax.Array, *, block_rows: int = BLOCK_ROW
     grid = (rows // block_rows,)
     out = pl.pallas_call(
         _kernel,
+        name="fused_cosine",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),

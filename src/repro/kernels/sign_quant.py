@@ -35,6 +35,7 @@ def sign_quant_2d(x2: jax.Array, *, block_rows: int = BLOCK_ROWS,
     assert rows % block_rows == 0
     return pl.pallas_call(
         _kernel,
+        name="sign_quant",
         grid=(rows // block_rows,),
         in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
         out_specs=[

@@ -56,6 +56,7 @@ def ssd_chunk_call(xdt: jax.Array, dA: jax.Array, B: jax.Array, C: jax.Array,
     grid = (b, h, nc)
     return pl.pallas_call(
         _kernel,
+        name="ssd_chunk",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, 1, Q, P), lambda ib, ih, ic: (ib, ih, ic, 0, 0)),

@@ -37,6 +37,7 @@ def topk_mask_2d(x2: jax.Array, threshold: jax.Array, *,
     t2 = jnp.reshape(threshold.astype(jnp.float32), (1, 1))
     return pl.pallas_call(
         _kernel,
+        name="topk_mask",
         grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),

@@ -54,7 +54,7 @@ from repro.models.build import build_model, syn_loss_fn, syn_spec_for, vision_sy
 from repro.models.cnn import DATASETS, accuracy, make_paper_model
 from repro.models.encdec import EncDec
 from repro.obs import (configure_tracer, get_registry, get_tracer,
-                       merge_traces, write_chrome_trace)
+                       merge_traces, watch_compiles, write_chrome_trace)
 
 
 class _ProfileWindow:
@@ -579,6 +579,7 @@ def main(argv=None):
                          "(0 picks a free port)")
     args = ap.parse_args(argv)
     enable_compile_cache()
+    watch_compiles()
     if args.trace:
         configure_tracer(True, proc="server")
     http = None

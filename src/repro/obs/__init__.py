@@ -17,11 +17,13 @@
 
 Everything here is HOST-side: spans wrap dispatch/transport/checkpoint
 boundaries, never jitted computation (use ``launch/train.py --profile``
-to capture the device timeline via ``jax.profiler``).
+to capture the device timeline via ``jax.profiler``; spans opened while
+it records land in that trace too). ``meters.watch_compiles`` counts the
+process's compiles into the registry and the span trace.
 """
 from repro.obs.log import get_logger
 from repro.obs.meters import (Counter, Gauge, Histogram, MetricsRegistry,
-                              get_registry, set_registry)
+                              get_registry, set_registry, watch_compiles)
 from repro.obs.trace import (Span, Tracer, configure_tracer, get_tracer,
                              merge_traces, read_trace_jsonl, set_tracer,
                              write_chrome_trace)
@@ -29,4 +31,4 @@ from repro.obs.trace import (Span, Tracer, configure_tracer, get_tracer,
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry", "Span",
            "Tracer", "configure_tracer", "get_logger", "get_registry",
            "get_tracer", "merge_traces", "read_trace_jsonl", "set_registry",
-           "set_tracer", "write_chrome_trace"]
+           "set_tracer", "watch_compiles", "write_chrome_trace"]

@@ -188,3 +188,31 @@ def set_registry(registry: MetricsRegistry) -> MetricsRegistry:
     global _GLOBAL
     _GLOBAL = registry
     return registry
+
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listener: Optional[Callable[..., None]] = None
+
+
+def watch_compiles() -> None:
+    """From now on, every backend compile of this process (a load from the
+    persistent compile cache included) counts in ``jax.compiles``, its
+    seconds go to the ``jax.compile_s`` histogram of the current registry,
+    and the span tracer records a ``jax.compile`` event: which round
+    recompiled. Registers one ``jax.monitoring`` listener per process."""
+    global _compile_listener
+    if _compile_listener is not None:
+        return
+    import jax.monitoring
+    from repro.obs.trace import get_tracer
+
+    def on_event(event: str, duration: float, **_: Any) -> None:
+        if event != COMPILE_EVENT:
+            return
+        registry = get_registry()
+        registry.counter("jax.compiles").inc()
+        registry.histogram("jax.compile_s").observe(duration)
+        get_tracer().event("jax.compile", seconds=duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    _compile_listener = on_event

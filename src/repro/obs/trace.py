@@ -12,6 +12,11 @@ buffer.  It is designed for the round hot path:
   timing being measured).
 - The ring is bounded (``capacity``); evictions are counted in
   ``dropped`` so truncation is visible, never silent.
+- While a ``jax.profiler`` session is active, every span is also a
+  ``jax.profiler.TraceAnnotation`` of the same name and tags, whether or
+  not the ring is enabled, so it lands in the profiler's trace on the
+  device timeline's clock. With no session that costs one
+  ``TraceAnnotation.is_enabled()`` check per span.
 
 Spans carry a ``proc`` label ("server", "client-3", ...) identifying the
 recording process.  Workers drain their rings and piggyback the dicts on
@@ -35,18 +40,28 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, Iterable, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 
 class Span:
-    """An open span; close it via the context-manager protocol or ``end()``."""
+    """An open span; close it via the context-manager protocol or ``end()``.
 
-    __slots__ = ("_tracer", "name", "tags", "t0", "t1")
+    ``tracer`` None records nothing in a ring; ``annotate`` opens a
+    profiler annotation of the same name and tags until ``end()``."""
 
-    def __init__(self, tracer: "Tracer", name: str, tags: Dict[str, Any]):
+    __slots__ = ("_tracer", "name", "tags", "t0", "t1", "_annotation")
+
+    def __init__(self, tracer: Optional["Tracer"], name: str,
+                 tags: Dict[str, Any], annotate: bool = False):
         self._tracer = tracer
         self.name = name
         self.tags = tags
-        self.t0 = tracer._clock()
+        self.t0 = tracer._clock() if tracer is not None else 0
         self.t1: Optional[int] = None
+        self._annotation = None
+        if annotate:
+            self._annotation = TraceAnnotation(name, **tags)
+            self._annotation.__enter__()
 
     def __enter__(self) -> "Span":
         return self
@@ -56,6 +71,11 @@ class Span:
 
     def end(self, **extra_tags: Any) -> None:
         if self.t1 is not None:
+            return
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        if self._tracer is None:
+            self.t1 = 0
             return
         self.t1 = self._tracer._clock()
         if extra_tags:
@@ -101,10 +121,13 @@ class Tracer:
     # -- recording ---------------------------------------------------------
 
     def span(self, name: str, **tags: Any):
-        """Open a span; use as ``with tracer.span("phase", round=r): ...``."""
+        """Open a span; use as ``with tracer.span("phase", round=r): ...``.
+        Under an active ``jax.profiler`` session it is also a
+        ``TraceAnnotation``, even with the ring disabled."""
+        annotate = TraceAnnotation.is_enabled()
         if not self.enabled:
-            return _NOOP_SPAN
-        return Span(self, name, tags)
+            return Span(None, name, tags, True) if annotate else _NOOP_SPAN
+        return Span(self, name, tags, annotate)
 
     def event(self, name: str, **tags: Any) -> None:
         """Record an instantaneous event."""

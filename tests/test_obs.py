@@ -60,6 +60,71 @@ def test_disabled_tracer_is_noop_and_allocation_free():
     assert t.to_dicts() == []
 
 
+def _profiled_host_events(tmp_path, body):
+    """Run ``body()`` under a ``jax.profiler`` session; returns the host
+    events of the trace as (name, {stat: value})."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    return [(e.name, dict(e.stats))
+            for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:")
+            for line in p.lines for e in line.events]
+
+
+@pytest.mark.parametrize("ring", [False, True])
+def test_span_lands_in_a_profiler_trace(tmp_path, ring):
+    """Under a profiler session a span is also a TraceAnnotation, with the
+    ring on or off; outside one a disabled tracer's span is the no-op."""
+    t = Tracer(enabled=ring)
+
+    def body():
+        with t.span("engine.dispatch", block=10, rounds_done=30) as sp:
+            sp.end(extra=1)
+
+    events = _profiled_host_events(tmp_path, body)
+    got = [stats for name, stats in events if name == "engine.dispatch"]
+    assert got == [{"block": 10, "rounds_done": 30}]
+    assert [r["name"] for r in t.drain()] == (["engine.dispatch"] if ring
+                                              else [])
+    if not ring:
+        assert t.span("engine.dispatch", rounds_done=0) is _NOOP_SPAN
+
+
+def test_watch_compiles_counts_a_fresh_jit():
+    import jax
+    import numpy as np
+
+    from repro.obs import get_registry, get_tracer, set_registry, set_tracer
+    from repro.obs.meters import watch_compiles
+
+    old_registry, old_tracer = get_registry(), get_tracer()
+    registry, tracer = MetricsRegistry(), Tracer(enabled=True)
+    set_registry(registry)
+    set_tracer(tracer)
+    try:
+        watch_compiles()
+        watch_compiles()                    # one listener per process
+        jax.jit(lambda x: x * 3.0 + 1.0)(np.ones((7, 3), np.float32))
+        snap = registry.snapshot()
+        assert snap["counters"]["jax.compiles"] == 1
+        assert snap["histograms"]["jax.compile_s"]["count"] == 1
+        assert snap["histograms"]["jax.compile_s"]["sum"] > 0
+        (ev,) = [r for r in tracer.drain() if r["name"] == "jax.compile"]
+        assert ev["kind"] == "event" and ev["seconds"] > 0
+    finally:
+        set_registry(old_registry)
+        set_tracer(old_tracer)
+
+
 def test_ring_bounds_memory_and_counts_drops():
     t = Tracer(enabled=True, capacity=4)
     for i in range(10):
