@@ -23,38 +23,17 @@ the same clock. From these:
 * a block's boundary gap is the idle time inside its ``bench.block`` span
   that lies before the first or after the last operation of its
   ``bench.run_block`` span: the time the chip waits for the host to
-  dispatch, fetch metrics and run the eval;
-* the program's own spans (``engine.dispatch`` around the block's
-  dispatch, ``engine.sync`` around its metrics fetch, from
-  ``repro.obs.trace.Tracer``) are on the same host planes, so an idle
-  interval is named by the innermost span, the benchmark's or the
-  program's, that is open in it;
-* the ``XLA Modules`` line of a chip says which executable ran when, so an
-  operation belongs to the executable whose run holds its start; given
-  that executable's optimized HLO text (``attach_hlo``), an operation's
-  phase is the innermost of the round's named scopes (``PHASES``) in its
-  instruction's ``op_name``, or, where that names none, the phase of the
-  instruction that calls its computation (a ``while`` body or a
-  ``conditional`` branch), and ``UNSCOPED`` where neither names one.
+  dispatch, fetch metrics and run the eval.
 """
 from __future__ import annotations
 
-import bisect
 import gzip
 import re
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
-MODULES_LINE = "XLA Modules"
-SPAN_PREFIXES = ("bench.", "engine.")   # the host spans read from a trace
 NS = 1e-9
-# the round's named scopes (repro.fl.round.PHASE_SCOPES)
-PHASES = ("fl.batch", "fl.local_train", "fl.encode", "fl.gather",
-          "fl.aggregate", "fl.update")
-UNSCOPED = "unscoped"
-_PHASE = re.compile(r"\bfl\.(?:batch|local_train|encode|gather|aggregate|"
-                    r"update)\b")
 
 
 class Op(NamedTuple):
@@ -114,79 +93,6 @@ def hlo_bytes(text: str) -> int:
     return total
 
 
-class HloNames(NamedTuple):
-    """What one executable's optimized HLO text says of its instructions."""
-    module: str                      # the executable's name, as ``jit_blk``
-    phase: Dict[str, str]            # instruction -> phase or UNSCOPED
-    kernel: Dict[str, str]           # tpu_custom_call instruction -> kernel
-
-
-_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
-_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%([\w.\-]+) = ")
-_OP_NAME = re.compile(r'metadata=\{[^{}]*?op_name="([^"]*)"')
-_CALLED = re.compile(r"\b(?:body|condition|calls|to_apply|branch_computations|"
-                     r"called_computations)=(?:\{([^}]*)\}|%?([\w.\-]+))")
-
-
-def read_hlo(text: str) -> HloNames:
-    """Each instruction's phase, and each Pallas call's kernel name, from an
-    executable's optimized HLO text (``compiled.as_text()``).
-
-    A kernel's name is the name stack's component before ``pallas_call``
-    (``pallas_call(name=...)`` puts it there), else the instruction's name
-    less its ``.N`` suffix."""
-    head = re.match(r"HloModule ([\w.\-]+)", text)
-    comp = None
-    comp_of: Dict[str, str] = {}
-    own: Dict[str, Optional[str]] = {}
-    caller: Dict[str, str] = {}
-    kernel: Dict[str, str] = {}
-    for line in text.splitlines():
-        if not line.startswith(" "):
-            m = _COMPUTATION.match(line)
-            comp = m.group(1) if m else None
-            continue
-        m = _INSTRUCTION.match(line)
-        if m is None or comp is None:
-            continue
-        name = m.group(1)
-        comp_of[name] = comp
-        op = _OP_NAME.search(line)
-        op_name = op.group(1) if op else ""
-        found = _PHASE.findall(op_name)
-        own[name] = found[-1] if found else None
-        for group, single in _CALLED.findall(line):
-            for c in (group or single).split(","):
-                caller.setdefault(c.strip().lstrip("%"), name)
-        if 'custom_call_target="tpu_custom_call"' in line:
-            parts = op_name.split("/")
-            kernel[name] = parts[-2] if len(parts) > 1 and \
-                parts[-1] == "pallas_call" else _stem(name)
-    phase: Dict[str, str] = {}
-    for name in own:
-        seen, cur = [], name
-        while cur is not None and cur not in phase and own[cur] is None:
-            seen.append(cur)
-            cur = caller.get(comp_of[cur])
-            if cur in seen:
-                cur = None
-        got = UNSCOPED if cur is None else phase.get(cur) or own[cur]
-        for n in seen + [name]:
-            phase.setdefault(n, got)
-    return HloNames(head.group(1) if head else "", phase, kernel)
-
-
-def _stem(instruction: str) -> str:
-    """``fused_cosine.3`` -> ``fused_cosine``."""
-    return re.sub(r"\.\d+$", "", instruction)
-
-
-def _instruction(name: str) -> str:
-    """An ``Op``'s name to its instruction's: ``fusion.12 = f32[8,128]
-    fusion`` -> ``fusion.12``."""
-    return name.split(" ", 1)[0].lstrip("%")
-
-
 def _stat(stats: Dict, *names: str) -> str:
     for n in names:
         if n in stats:
@@ -197,8 +103,7 @@ def _stat(stats: Dict, *names: str) -> str:
 class TraceView:
     """Per-chip operations and the host spans of one traced window."""
 
-    def __init__(self, chips: List[List[Op]], spans: List[Span],
-                 modules: Optional[List[List[Span]]] = None):
+    def __init__(self, chips: List[List[Op]], spans: List[Span]):
         self.chips = chips
         self.spans = sorted(spans)
         blocks = [s for s in self.spans if s.name == "bench.block"]
@@ -208,28 +113,6 @@ class TraceView:
         self.t0, self.t1 = blocks[0].start, blocks[-1].end
         self._merged = [_union([(o.start, o.end) for o in ops])
                         for ops in chips]
-        # per chip, the runs of each executable (XLA Modules), by start
-        self.modules = [sorted(m) for m in modules] if modules \
-            else [[] for _ in chips]
-        self._hlo: Dict[str, HloNames] = {}
-        self._edges: Dict[Tuple[int, int], Tuple[float, float]] = {}
-        self._self: Dict[int, List[Tuple[Op, float]]] = {}
-        self._prefix: Dict[int, Tuple[list, list, list]] = {}
-        self._split: Optional[Dict[str, float]] = None
-        self._phases: Optional[Dict[str, float]] = None
-        self._cuts: Optional[List[float]] = None
-        self._inner: List[str] = []
-
-    def attach_hlo(self, text: str) -> HloNames:
-        """Name the operations of the executable whose optimized HLO text
-        this is (``read_hlo``) by phase and kernel."""
-        names = read_hlo(text)
-        self._hlo[names.module] = names
-        self._phases = None
-        return names
-
-    def has_span(self, name: str) -> bool:
-        return any(s.name == name for s in self.spans)
 
     # -- window -------------------------------------------------------------
     def window_s(self) -> float:
@@ -253,157 +136,30 @@ class TraceView:
             / len(self.chips)
 
     # -- host boundaries ----------------------------------------------------
-    def _scan_edges(self, k: int, chip: int) -> Tuple[float, float]:
-        """First start and last end of chip ``chip``'s operations inside
-        block ``k``'s ``bench.run_block`` span (the block's own scan); both
-        the block's start where there are none."""
-        if (k, chip) not in self._edges:
-            blk = self.blocks[k]
-            inner = [s for s in self.spans if s.name == "bench.run_block"
-                     and s.start >= blk.start and s.end <= blk.end]
-            mine = [o for o in self.chips[chip]
-                    if inner and o.start >= inner[0].start
-                    and o.end <= inner[0].end]
-            if mine:
-                edges = (min(o.start for o in mine), max(o.end for o in mine))
-            else:
-                edges = (blk.start, blk.start)
-            self._edges[k, chip] = edges
-        return self._edges[k, chip]
-
     def boundary_gaps_s(self) -> List[float]:
         """Per block, the chips' idle seconds outside the span of the
         block's own scan (averaged over chips)."""
+        dispatch = [s for s in self.spans if s.name == "bench.run_block"]
         out = []
-        for k, blk in enumerate(self.blocks):
+        for blk in self.blocks:
+            inner = [s for s in dispatch
+                     if s.start >= blk.start and s.end <= blk.end]
             gap = 0.0
-            for chip, merged in enumerate(self._merged):
-                a, b = self._scan_edges(k, chip)
+            for ops, merged in zip(self.chips, self._merged):
+                mine = [o for o in ops if inner and o.start >= inner[0].start
+                        and o.end <= inner[0].end]
+                if mine:
+                    a, b = min(o.start for o in mine), max(o.end for o in mine)
+                else:
+                    a = b = blk.start
                 gap += (a - blk.start) - _clip_len(merged, blk.start, a)
                 gap += (blk.end - b) - _clip_len(merged, b, blk.end)
             out.append(gap * NS / len(self.chips))
         return out
 
-    def _busy_until(self, chip: int, t: float) -> float:
-        """Busy nanoseconds of chip ``chip`` before ``t``."""
-        if chip not in self._prefix:
-            merged = self._merged[chip]
-            cum = [0.0]
-            for s, e in merged:
-                cum.append(cum[-1] + e - s)
-            self._prefix[chip] = ([s for s, _ in merged],
-                                  [e for _, e in merged], cum)
-        starts, ends, cum = self._prefix[chip]
-        k = bisect.bisect_right(starts, t)
-        if k == 0:
-            return 0.0
-        return cum[k - 1] + min(t, ends[k - 1]) - starts[k - 1]
-
-    def _innermost_scan(self, t: float) -> str:
-        open_ = [s for s in self.spans if s.start <= t <= s.end]
-        return min(open_, key=lambda s: s.end - s.start).name \
-            if open_ else "none"
-
-    def _innermost(self, t: float) -> str:
-        """The shortest span open at ``t`` (both ends included), or
-        "none"; looked up in the segments between span edges, which each
-        have one innermost span."""
-        if self._cuts is None:
-            self._cuts = sorted({x for s in self.spans
-                                 for x in (s.start, s.end)})
-            self._inner = ["none"] + [
-                self._innermost_scan((a + b) / 2)
-                for a, b in zip(self._cuts, self._cuts[1:])] + ["none"]
-        k = bisect.bisect_left(self._cuts, t)
-        if k < len(self._cuts) and self._cuts[k] == t:
-            return self._innermost_scan(t)
-        return self._inner[k]
-
-    def boundary_idle_s(self) -> Dict[str, float]:
-        """The idle time of ``boundary_gaps_s`` (summed over the blocks,
-        averaged over the chips) by the innermost span open in it:
-        ``engine.dispatch``, ``engine.sync``, ``bench.eval``, or a
-        benchmark span with no program span inside."""
-        if self._split is not None:
-            return self._split
-        out: Dict[str, float] = {}
-        for k, blk in enumerate(self.blocks):
-            for chip in range(len(self.chips)):
-                a, b = self._scan_edges(k, chip)
-                for p, q in ((blk.start, a), (b, blk.end)):
-                    cuts = sorted({p, q} | {x for s in self.spans
-                                            for x in (s.start, s.end)
-                                            if p < x < q})
-                    for x, y in zip(cuts, cuts[1:]):
-                        idle = (y - x) - (self._busy_until(chip, y)
-                                          - self._busy_until(chip, x))
-                        if idle > 0:
-                            name = self._innermost((x + y) / 2)
-                            out[name] = out.get(name, 0.0) + idle * NS
-        self._split = {k: v / len(self.chips) for k, v in out.items()}
-        return self._split
-
-    # -- executables and phases ---------------------------------------------
-    def _self_ops(self, chip: int) -> List[Tuple[Op, float]]:
-        """Chip ``chip``'s operations inside the window, each with its self
-        time in nanoseconds."""
-        if chip not in self._self:
-            inside = [o for o in self.chips[chip]
-                      if o.start >= self.t0 and o.end <= self.t1]
-            self._self[chip] = list(zip(inside, _self_times(inside)))
-        return self._self[chip]
-
-    def _names_of(self, chip: int, op: Op) -> Optional[HloNames]:
-        """The attached HLO names of the executable whose run holds
-        ``op``'s start, if any."""
-        mods = self.modules[chip]
-        k = bisect.bisect_right(mods, (op.start, float("inf"))) - 1
-        if k < 0 or op.start > mods[k].end:
-            return None
-        return self._hlo.get(mods[k].name)
-
-    def phase_s(self) -> Optional[Dict[str, float]]:
-        """Self seconds of the operations of the executables whose HLO text
-        is attached, by phase (``PHASES`` or ``UNSCOPED``; an instruction
-        the text lacks is ``UNSCOPED``), averaged over the chips. None where
-        no attached executable ran in the window."""
-        if self._phases is None:
-            out: Dict[str, float] = {}
-            for chip in range(len(self.chips)):
-                for o, t in self._self_ops(chip):
-                    names = self._names_of(chip, o)
-                    if names is not None:
-                        ph = names.phase.get(_instruction(o.name), UNSCOPED)
-                        out[ph] = out.get(ph, 0.0) + t * NS
-            self._phases = {k: v / len(self.chips) for k, v in out.items()}
-        return self._phases or None
-
-    def hlo_matched(self) -> Tuple[int, int]:
-        """(operations found in their executable's attached HLO text,
-        operations of executables with attached text), over the chips."""
-        found = total = 0
-        for chip in range(len(self.chips)):
-            for o, _ in self._self_ops(chip):
-                names = self._names_of(chip, o)
-                if names is not None:
-                    total += 1
-                    found += _instruction(o.name) in names.phase
-        return found, total
-
-    def _label(self, chip: int, op: Op) -> str:
-        """A Pallas call by its kernel's name, any other operation by its
-        instruction."""
-        if not op.pallas:
-            return op.name
-        names = self._names_of(chip, op)
-        instr = _instruction(op.name)
-        kernel = names.kernel.get(instr) if names is not None else None
-        return (kernel or _stem(instr)) + " tpu_custom_call"
-
     def idle_gaps(self) -> List[Tuple[str, float]]:
         """Every idle interval of chip 0 inside the window, named by the
-        innermost span (the benchmark's or the program's) open at its
-        midpoint."""
+        innermost benchmark span open at its midpoint."""
         merged = [(s, e) for s, e in self._merged[0]
                   if e > self.t0 and s < self.t1]
         edges = [self.t0] + [x for s, e in merged for x in (s, e)] + [self.t1]
@@ -412,54 +168,37 @@ class TraceView:
             a, b = max(a, self.t0), min(b, self.t1)
             if b <= a:
                 continue
-            out.append((self._innermost((a + b) / 2), (b - a) * NS))
+            mid = (a + b) / 2
+            open_ = [s for s in self.spans if s.start <= mid <= s.end]
+            name = min(open_, key=lambda s: s.end - s.start).name \
+                if open_ else "none"
+            out.append((name, (b - a) * NS))
         return out
 
     def breakdown(self, top: int = 10) -> Dict[str, list]:
         """The operations with the most self time (summed over the chips,
-        by instruction; Pallas calls by kernel) and the longest idle gaps
-        of chip 0; the boundary's idle seconds by span; with HLO text
-        attached, the seconds by phase and the unscoped operations with the
-        most self time."""
+        by instruction) and the longest idle gaps of chip 0."""
         totals: Dict[str, float] = {}
-        unscoped: Dict[str, float] = {}
-        for chip in range(len(self.chips)):
-            for o, t in self._self_ops(chip):
-                key = self._label(chip, o)
-                totals[key] = totals.get(key, 0.0) + t * NS
-                names = self._names_of(chip, o)
-                if names is not None and names.phase.get(
-                        _instruction(o.name), UNSCOPED) == UNSCOPED:
-                    unscoped[key] = unscoped.get(key, 0.0) + t * NS
-
-        def _top(d):
-            return [[k, v] for k, v in
-                    sorted(d.items(), key=lambda kv: -kv[1])[:top]]
-
-        out = {"device_ops": _top(totals),
-               "idle_gaps": [[k, v] for k, v in sorted(
-                   self.idle_gaps(), key=lambda kv: -kv[1])[:top]],
-               "boundary_idle_s": self.boundary_idle_s()}
-        phases = self.phase_s()
-        if phases is not None:
-            out["phase_s"] = phases
-            out["unscoped_ops"] = _top(unscoped)
-        return out
+        for ops in self.chips:
+            inside = [o for o in ops if o.start >= self.t0 and o.end <= self.t1]
+            for o, t in zip(inside, _self_times(inside)):
+                totals[o.name] = totals.get(o.name, 0.0) + t * NS
+        ops_top = sorted(totals.items(), key=lambda kv: -kv[1])[:top]
+        gaps = sorted(self.idle_gaps(), key=lambda kv: -kv[1])[:top]
+        return {"device_ops": [[k, v] for k, v in ops_top],
+                "idle_gaps": [[k, v] for k, v in gaps]}
 
 
 def _self_times(ops: Sequence[Op]) -> List[float]:
     """Each operation's duration less that of the operations it encloses
-    (``ops`` as recorded on one chip's line). An operation's parent is the
-    latest-starting one still open at its end: the trace rounds times to
-    whole nanoseconds, so a sibling can seem to end a nanosecond after its
-    neighbour starts, and is then no parent."""
+    (``ops`` as recorded on one chip's line)."""
     order = sorted(range(len(ops)), key=lambda i: (ops[i].start, -ops[i].end))
     self_t = [o.end - o.start for o in ops]
     stack: List[int] = []
     for i in order:
-        while stack and ops[stack[-1]].end < ops[i].end:
+        while stack and ops[stack[-1]].end <= ops[i].start:
             stack.pop()
-        if stack:
+        if stack and ops[i].end <= ops[stack[-1]].end:
             self_t[stack[-1]] -= ops[i].end - ops[i].start
         stack.append(i)
     return self_t
@@ -479,17 +218,12 @@ def _short(text: str) -> str:
 def from_profile(pd, chips: Optional[int] = None) -> TraceView:
     """Build the view from a ``jax.profiler.ProfileData``."""
     devices: Dict[int, List[Op]] = {}
-    modules: Dict[int, List[Span]] = {}
     spans: List[Span] = []
     for plane in pd.planes:
         m = DEVICE_PLANE.match(plane.name)
         if m:
-            ops, mods = [], []
+            ops = []
             for line in plane.lines:
-                if line.name == MODULES_LINE:
-                    mods += [Span(e.start_ns, e.start_ns + e.duration_ns,
-                                  e.name.split("(", 1)[0])
-                             for e in line.events]
                 if line.name != OPS_LINE:
                     continue
                 for e in line.events:
@@ -499,11 +233,10 @@ def from_profile(pd, chips: Optional[int] = None) -> TraceView:
                                   _short(text), pallas,
                                   hlo_bytes(text) if pallas else 0))
             devices[int(m.group(1))] = ops
-            modules[int(m.group(1))] = mods
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
-                    if e.name.startswith(SPAN_PREFIXES):
+                    if e.name.startswith("bench."):
                         spans.append(Span(e.start_ns,
                                           e.start_ns + e.duration_ns, e.name))
     ids = sorted(devices)
@@ -511,8 +244,7 @@ def from_profile(pd, chips: Optional[int] = None) -> TraceView:
         ids = ids[:chips]
     if not ids:
         raise ValueError("the trace holds no TPU plane")
-    return TraceView([devices[i] for i in ids], spans,
-                     [modules[i] for i in ids])
+    return TraceView([devices[i] for i in ids], spans)
 
 
 def load(path: str, chips: Optional[int] = None) -> TraceView:

@@ -137,13 +137,6 @@ class VisionProgram:
     def params(self):
         return host_params(self.state.params)
 
-    def block_hlo_text(self):
-        """The block executable's optimized HLO text
-        (``RoundEngine.block_hlo_text``), or None where the engine gives
-        none."""
-        get = getattr(self.engine, "block_hlo_text", None)
-        return None if get is None else get(self.state, self.every)
-
     def describe(self) -> str:
         s = self.engine.stats
         return (f"engine: {s.dispatches} dispatches, {s.host_syncs} host "

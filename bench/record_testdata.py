@@ -4,11 +4,8 @@
 
 Builds the ``mlp-3sfc`` cell at a toy size (2,000 images, 2 clients, one
 local step of batch 8, 2-round blocks), warms it, and traces three blocks
-with the benchmark's own spans (and the program's, which a profiler
-session records). Writes the gzipped trace to ``OUT`` and the block
-executable's optimized HLO text, gzipped, beside it as ``OUT`` with
-``.hlo.txt.gz`` in place of ``.xplane.pb.gz``; copy both to
-``bench/testdata/`` to replace the committed ``scoped.*`` pair.
+with the benchmark's own spans. Writes the gzipped trace to ``OUT``; copy
+it to ``bench/testdata/tiny.xplane.pb.gz`` to replace the committed one.
 """
 from __future__ import annotations
 
@@ -53,11 +50,7 @@ def main(dst: str) -> None:
     with open(path, "rb") as f, gzip.open(dst, "wb") as g:
         g.write(f.read())
     shutil.rmtree(tdir)
-    hlo = dst.replace(".xplane.pb.gz", "") + ".hlo.txt.gz"
-    with gzip.open(hlo, "wt") as g:
-        g.write(program.block_hlo_text())
-    for p in (dst, hlo):
-        print(f"{p}: {os.path.getsize(p)} bytes")
+    print(f"{dst}: {os.path.getsize(dst)} bytes")
 
 
 if __name__ == "__main__":

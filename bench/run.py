@@ -12,9 +12,7 @@ call (set-up, compilation included), then measures back-to-back blocks for
 
 ``--trace 0`` prints the cell's end-to-end metrics; ``--trace 1`` runs a
 short window under ``jax.profiler`` and prints its per-layer metrics (one
-reader per metric in ``bench/metrics/``) and a breakdown; after the window
-it takes the block executable's optimized HLO text from the program, which
-names each traced operation by the round's phase scopes. Either way the
+reader per metric in ``bench/metrics/``) and a breakdown. Either way the
 run then frees the program, follows the same first blocks with the plain
 reference and compares (``bench/correct.py``). The last stdout line is one
 JSON object; the numbers compared, each beside its limit, are also the last
@@ -224,9 +222,6 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, peaks):
         w, view = window(program, seconds), None
     counter.close()
     peak = peak_bytes(used)
-    # the block's HLO text names the traced operations by phase; fetched
-    # after the window (a compile-cache load), only when tracing
-    hlo = program.block_hlo_text() if trace else None
     print(f"device: {used[0].platform} {used[0].device_kind} x{len(used)} "
           f"(of {len(devices)})")
     print(f"{program.describe()}; window {len(w.block_s)} blocks, {w.rounds} "
@@ -238,14 +233,6 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, peaks):
         ops = view.pallas_ops()
         print(f"pallas: {len(ops)} calls, {sum(o.nbytes > 0 for o in ops)} "
               f"with HBM operands, {sum(o.nbytes for o in ops)} HBM bytes")
-        if hlo:
-            names = view.attach_hlo(hlo)
-            found, total = view.hlo_matched()
-            print(f"hlo: executable {names.module}: {found} of its {total} "
-                  f"operations in the window found in its text")
-        else:
-            print("hlo: the program gives no block HLO text; the metrics "
-                  "by phase are left out")
 
     program.close()
     del program

@@ -1,6 +1,6 @@
 """The round's phase scopes in the cell's block executable, on the CPU at
 a toy size: the optimized HLO names every phase of a vmap round in its
-op_names, as ``bench/devtrace.py`` reads them, and the scopes change no
+op_names, as ``bench/phasetrace.py`` reads them, and the scopes change no
 instruction."""
 import contextlib
 import os
@@ -13,7 +13,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
-from bench import devtrace, spec  # noqa: E402
+from bench import phasetrace, spec  # noqa: E402
 from bench.families import vision  # noqa: E402
 from repro.fl.round import PHASE_SCOPES  # noqa: E402
 
@@ -29,7 +29,7 @@ def _block_text() -> str:
     cell = spec.Cell(spec.benchmark(), "mlp-3sfc")
     program = vision.Program(dict(cell.config, **TINY_CONFIG),
                              dict(cell.traffic, **TINY_TRAFFIC), 7)
-    return program.block_hlo_text()
+    return program.engine.block_hlo_text(program.state, program.every)
 
 
 def _instructions(text: str):
@@ -51,7 +51,7 @@ def test_block_names_every_phase_and_scopes_change_no_instruction(
     op_names = re.findall(r'op_name="([^"]*)"', text)
     for scope in VMAP_PHASES:
         assert any(scope in o for o in op_names), scope
-    names = devtrace.read_hlo(text)
+    names = phasetrace.read_hlo(text)
     assert names.module == "jit_blk"
     assert set(VMAP_PHASES) <= set(names.phase.values())
     monkeypatch.setattr(jax, "named_scope",
@@ -63,4 +63,4 @@ def test_block_names_every_phase_and_scopes_change_no_instruction(
 
 
 def test_devtrace_reads_the_programs_scopes():
-    assert devtrace.PHASES == PHASE_SCOPES
+    assert phasetrace.PHASES == PHASE_SCOPES

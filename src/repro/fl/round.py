@@ -83,7 +83,7 @@ CLIENT_SCOPE = "fl_client_local"
 
 # Named scopes of the round's phases, the same in both fan-outs. They add
 # op_name metadata only (the compiled instructions are unchanged), so a
-# profiler trace of the round can be split by phase (bench/devtrace.py).
+# profiler trace of the round can be split by phase (bench/phasetrace.py).
 # BATCH_SCOPE wraps the engine's batch gather (fl/engine.py).
 BATCH_SCOPE = "fl.batch"
 LOCAL_TRAIN_SCOPE = "fl.local_train"
