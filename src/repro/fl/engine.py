@@ -134,8 +134,30 @@ def device_pools(parts: Sequence[np.ndarray]) -> ClientPools:
 def vision_batcher(train_x: np.ndarray, train_y: np.ndarray,
                    pools: ClientPools, local_steps: int,
                    local_batch: int) -> BatchFn:
-    """Non-iid ``{"x", "y"}`` batches gathered from device-resident data."""
-    x = jnp.asarray(train_x)
+    """Non-iid ``{"x", "y"}`` batches gathered from device-resident data.
+
+    The batch contract: ``x`` is ``(N, K, B, *sample_shape)`` in the set's
+    dtype, ``y`` is ``(N, K, B)``, and client ``i``'s values are
+    ``train_x[pools.index[i, pos]]`` for the positions the PRNG contract
+    (module docstring) draws.
+
+    How the set sits on the device: once, here and outside any jit, it is
+    stored as sample-contiguous rows ``(n, m)``, ``m = prod(sample_shape)``;
+    the round gathers whole rows by the same indices and reshapes the
+    gathered ``(N, K, B, m)`` to ``(N, K, B, *sample_shape)``, so the batch
+    is the set's values bit for bit. Why, on a TPU, whose tiles span an
+    array's two minor dimensions by (8, 128): stored as ``(n, 28, 28, 1)``
+    the set has no layout that tiles well. Its default layout puts the
+    sample index in the lanes (``{0,3,2,1:T(1,128)}``), each block relaid
+    the whole set into one (32, 128)-padded bf16 tile per image, and the
+    gather then cut the batch out of those tiles one image at a time. As
+    rows, the block's one pass over the set writes rows of 784 values
+    (``bf16[n,784]{1,0}``) and the gather moves whole rows. Rows padded to
+    whole 128-lane tiles gather no faster and hold more memory.
+    """
+    sample_shape = tuple(train_x.shape[1:])
+    # a free view of a host set, reshaped before its one upload
+    rows = jnp.asarray(train_x.reshape(train_x.shape[0], -1))
     y = jnp.asarray(train_y)
     num_clients = pools.index.shape[0]
 
@@ -149,7 +171,8 @@ def vision_batcher(train_x: np.ndarray, train_y: np.ndarray,
             return pools.index[i, pos]
 
         idx = jax.vmap(per_client)(jnp.arange(num_clients))
-        return {"x": x[idx], "y": y[idx]}
+        x = rows[idx].reshape(*idx.shape, *sample_shape)
+        return {"x": x, "y": y[idx]}
 
     return batch_fn
 

@@ -229,6 +229,62 @@ def test_device_pools_zero_sample_client_clamped():
     assert pools2.index.shape == (2, 1) and pools2.size.tolist() == [1, 1]
 
 
+def _contract_rows(pools, key, rnd, k, b):
+    """``pools.index[i, pos_i]`` by the PRNG contract, client by client."""
+    kr = jax.random.fold_in(key, rnd)
+    return np.stack([np.asarray(pools.index[i, jax.random.randint(
+        jax.random.fold_in(kr, i), (k, b), 0, pools.size[i])])
+        for i in range(pools.index.shape[0])])
+
+
+@pytest.mark.parametrize("sample_shape", [(28, 28, 1), (32, 32, 3)])
+def test_vision_batcher_batch_is_the_set_gathered_by_the_contract(
+        sample_shape):
+    """The sample-contiguous storage changes where the set's bytes sit, not
+    the batch: for a host set and a device set, every round's ``x`` is
+    bitwise ``train_x[pools.index[i, pos]]``, in the set's shape and dtype,
+    and ``y`` is ``train_y`` at the same rows."""
+    rng = np.random.default_rng(3)
+    x = rng.standard_normal((120, *sample_shape)).astype(np.float32)
+    y = rng.integers(0, 10, 120).astype(np.int32)
+    pools = device_pools(dirichlet_partition(y, 3, alpha=0.5, seed=1,
+                                             min_per_client=4))
+    key = jax.random.PRNGKey(11)
+    for train_x in (x, jnp.asarray(x)):
+        bf = jax.jit(vision_batcher(train_x, y, pools, 2, 5))
+        for rnd in range(3):
+            batch = bf(key, jnp.int32(rnd))
+            rows = _contract_rows(pools, key, rnd, 2, 5)
+            assert batch["x"].shape == (3, 2, 5, *sample_shape)
+            assert batch["x"].dtype == x.dtype
+            np.testing.assert_array_equal(np.asarray(batch["x"]), x[rows])
+            np.testing.assert_array_equal(np.asarray(batch["y"]), y[rows])
+
+
+def _gathers(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _gathers(sub)
+
+
+@pytest.mark.parametrize("sample_shape", [(28, 28, 1), (32, 32, 3)])
+def test_vision_batcher_gathers_whole_rows(sample_shape):
+    """The image gather reads the set stored once as sample-contiguous rows
+    ``(n, prod(sample_shape))``, one row per sample, and never the
+    ``(n, *sample_shape)`` set, whose tiling pads each image on a TPU."""
+    n = 40
+    x = np.zeros((n, *sample_shape), np.float32)
+    y = np.zeros((n,), np.int32)
+    pools = device_pools([np.arange(20), np.arange(20, 40)])
+    bf = vision_batcher(x, y, pools, 2, 3)
+    jaxpr = jax.make_jaxpr(bf)(jax.random.PRNGKey(0), jnp.int32(0)).jaxpr
+    operands = [tuple(e.invars[0].aval.shape) for e in _gathers(jaxpr)]
+    assert (n, int(np.prod(sample_shape))) in operands, operands
+    assert (n, *sample_shape) not in operands, operands
+
+
 def test_benchmarks_run_only_badname_exits_2(capsys):
     from benchmarks import run as bench_run
     with pytest.raises(SystemExit) as e:
