@@ -137,6 +137,12 @@ class VisionProgram:
     def params(self):
         return host_params(self.state.params)
 
+    def block_hlo(self) -> str:
+        """Optimized HLO text of the block executable ``run_block`` drives,
+        with the op_names that carry the program's scopes; compiles or
+        loads it from the compile cache, and runs nothing."""
+        return self.engine.block_hlo_text(self.state, self.every)
+
     def describe(self) -> str:
         s = self.engine.stats
         return (f"engine: {s.dispatches} dispatches, {s.host_syncs} host "

@@ -20,11 +20,34 @@ client and round:
 
 Per round, the server adds ``N * d`` for the mean of the messages and
 ``d`` for the update.
+
+This module counts the ``vision`` family. Any other family brings its own
+count as ``bench/families/<family>_flops.py`` with the same two functions,
+``param_count(config)`` and ``round_flops(config, traffic)``;
+``for_family`` finds it by the configuration's ``family``.
 """
 from __future__ import annotations
 
+import importlib
 import math
-from typing import Dict, List
+import os
+import sys
+from types import ModuleType
+from typing import Dict, List, Optional
+
+from bench import spec
+
+
+def for_family(config: Dict) -> Optional[ModuleType]:
+    """The module that counts ``config``'s family: this one for ``vision``,
+    else ``bench/families/<family>_flops.py``, or None where there is no
+    such file."""
+    family = config["family"]
+    if family == "vision":
+        return sys.modules[__name__]
+    if not os.path.exists(spec.family_path(f"{family}_flops")):
+        return None
+    return importlib.import_module(f"bench.families.{family}_flops")
 
 
 def _out_hw(h: int, stride: int) -> int:

@@ -12,7 +12,10 @@ call (set-up, compilation included), then measures back-to-back blocks for
 
 ``--trace 0`` prints the cell's end-to-end metrics; ``--trace 1`` runs a
 short window under ``jax.profiler`` and prints its per-layer metrics (one
-reader per metric in ``bench/metrics/``) and a breakdown. Either way the
+reader per metric in ``bench/metrics/``) and a breakdown; after that
+window, where the family's program gives its block executable's optimized
+HLO text (``block_hlo``), the trace's operations are named by the
+program's scopes from it (``bench/devtrace.py``). Either way the
 run then frees the program, follows the same first blocks with the plain
 reference and compares (``bench/correct.py``). The last stdout line is one
 JSON object; the numbers compared, each beside its limit, are also the last
@@ -118,6 +121,32 @@ def load_reader(name: str):
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
     return mod.read
+
+
+def layer_context(cell, view, rounds: int, blocks: int, peaks):
+    """What a per-layer reader reads: the traced window's ``view``, its
+    ``rounds`` and ``blocks``, the cell's ``chips``, ``config`` and
+    ``traffic``, the device's ``peaks``, and ``round_flops``, the
+    operations a round requires by the family's count
+    (``flops.for_family``), or None where the family brings none."""
+    counter = flops.for_family(cell.config)
+    round_flops = None if counter is None else \
+        counter.round_flops(cell.config, cell.traffic)
+    return SimpleNamespace(view=view, rounds=rounds, blocks=blocks,
+                           chips=cell.chips, config=cell.config,
+                           traffic=cell.traffic, peaks=peaks,
+                           round_flops=round_flops)
+
+
+def layer_metrics(cell, ctx):
+    """The cell's per-layer metrics, each read by its own reader; one whose
+    reader finds nothing to read (None) is left out."""
+    metrics = {}
+    for m in cell.per_layer:
+        value = load_reader(m["name"])(ctx)
+        if value is not None:
+            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    return metrics
 
 
 class CompileCounter:
@@ -234,6 +263,11 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, peaks):
         print(f"pallas: {len(ops)} calls, {sum(o.nbytes > 0 for o in ops)} "
               f"with HBM operands, {sum(o.nbytes for o in ops)} HBM bytes")
 
+    if trace and hasattr(program, "block_hlo"):
+        view.attach_hlo(program.block_hlo())
+        print("block HLO: {} of {} traced operations found; ".format(
+            *view.hlo_matched()) + f"self time {view.block_self_s()!r} s, "
+              f"unscoped {view.unscoped_s()!r} s")
     program.close()
     del program
     gc.collect()
@@ -246,14 +280,8 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, peaks):
           + ", ".join(f"{k} {v!r}" for k, v in values.items()))
 
     if trace:
-        ctx = SimpleNamespace(
-            view=view, rounds=w.rounds, blocks=len(w.block_s), chips=cell.chips,
-            peaks=peaks, round_flops=flops.round_flops(cell.config, cell.traffic))
-        metrics = {}
-        for m in cell.per_layer:
-            value = load_reader(m["name"])(ctx)
-            if value is not None:
-                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+        metrics = layer_metrics(
+            cell, layer_context(cell, view, w.rounds, len(w.block_s), peaks))
     else:
         values_e2e = {
             "rounds_per_s": w.rounds / w.wall_s,

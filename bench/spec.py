@@ -11,7 +11,9 @@ own, at a path made from its name:
   the readings they were set from;
 * ``bench/metrics/<metric>.py``: the reader of one per-layer metric;
 * ``bench/families/<family>.py`` and ``<family>_ref.py``: the program
-  under test and its plain reference, for a configuration's ``family``.
+  under test and its plain reference, for a configuration's ``family``;
+  ``<family>_flops.py``: the operations its round requires
+  (``flops.for_family``; the ``vision`` family's are ``bench/flops.py``).
 
 Adding a cell adds files and entries; it edits none of these.
 """

@@ -1,7 +1,8 @@
 """The round's phase scopes in the cell's block executable, on the CPU at
 a toy size: the optimized HLO names every phase of a vmap round in its
-op_names, as ``bench/phasetrace.py`` reads them, and the scopes change no
-instruction."""
+op_names, as ``bench/devtrace.py`` reads them, the scopes change no
+instruction, and the benchmark's phase readers read every scope the
+program names."""
 import contextlib
 import os
 import re
@@ -13,7 +14,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
-from bench import phasetrace, spec  # noqa: E402
+from bench import devtrace, run, spec  # noqa: E402
 from bench.families import vision  # noqa: E402
 from repro.fl.round import PHASE_SCOPES  # noqa: E402
 
@@ -29,7 +30,7 @@ def _block_text() -> str:
     cell = spec.Cell(spec.benchmark(), "mlp-3sfc")
     program = vision.Program(dict(cell.config, **TINY_CONFIG),
                              dict(cell.traffic, **TINY_TRAFFIC), 7)
-    return program.engine.block_hlo_text(program.state, program.every)
+    return program.block_hlo()
 
 
 def _instructions(text: str):
@@ -51,9 +52,9 @@ def test_block_names_every_phase_and_scopes_change_no_instruction(
     op_names = re.findall(r'op_name="([^"]*)"', text)
     for scope in VMAP_PHASES:
         assert any(scope in o for o in op_names), scope
-    names = phasetrace.read_hlo(text)
+    names = devtrace.read_hlo(text)
     assert names.module == "jit_blk"
-    assert set(VMAP_PHASES) <= set(names.phase.values())
+    assert set(VMAP_PHASES) <= {s for c in names.chain.values() for s in c}
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     bare = _block_text()
@@ -63,4 +64,10 @@ def test_block_names_every_phase_and_scopes_change_no_instruction(
 
 
 def test_devtrace_reads_the_programs_scopes():
-    assert phasetrace.PHASES == PHASE_SCOPES
+    """The cell's per-layer readers between them read every phase scope of
+    the round (a reader names the scopes it reads in ``SCOPES``)."""
+    read = set()
+    for m in spec.Cell(spec.benchmark(), "mlp-3sfc").per_layer:
+        reader = run.load_reader(m["name"])
+        read |= set(reader.__globals__.get("SCOPES", ()))
+    assert read == set(PHASE_SCOPES)

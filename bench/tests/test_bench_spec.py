@@ -38,7 +38,9 @@ def test_every_cell_loads_by_name():
 def test_config_layers_match_the_parameter_count():
     for c in BENCH["configs"]:
         config = spec.load_json(spec.config_path(c["name"]))
-        assert flops.param_count(config) == config["params"], c["name"]
+        counter = flops.for_family(config)
+        assert counter is not None, c["name"]
+        assert counter.param_count(config) == config["params"], c["name"]
         assert config["reduced"] == c["reduced"]
 
 

@@ -12,14 +12,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 sys.path.insert(0, ROOT)
 
 from bench import devtrace, spec  # noqa: E402
-
-KERNEL = ('%custom-call.1 = f32[8,128]{1,0} custom-call(f32[8,128]{1,0} %p), '
-          'custom_call_target="tpu_custom_call"')
-
-
-def _ev(name, start, end, **stats):
-    return NS(name=name, start_ns=float(start), duration_ns=float(end - start),
-              stats=list(stats.items()))
+from bench.tests._traces import KERNEL, ev as _ev  # noqa: E402
 
 
 def made_up_trace():
