@@ -108,9 +108,9 @@ def make_mnistnet(spec: VisionSpec) -> VisionModel:
         }
 
     def apply(p, x):
-        h = jax.nn.relu(layers.conv2d(p["c1"], x))
+        h = layers.conv2d(p["c1"], x, act=jax.nn.relu)
         h = jax.lax.reduce_window(h, -jnp.inf, jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
-        h = jax.nn.relu(layers.conv2d(p["c2"], h))
+        h = layers.conv2d(p["c2"], h, act=jax.nn.relu)
         h = jax.lax.reduce_window(h, -jnp.inf, jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
         h = h.reshape(h.shape[0], -1)
         h = jax.nn.relu(h @ p["f1"]["w"] + p["f1"]["b"])
@@ -141,7 +141,8 @@ def make_convnet(spec: VisionSpec, widths=(32, 64, 128, 256)) -> VisionModel:
     def apply(p, x):
         h = x
         for i in range(len(widths)):
-            h = jax.nn.relu(layers.conv2d(p[f"c{i}"], h, stride=2 if i else 1))
+            h = layers.conv2d(p[f"c{i}"], h, stride=2 if i else 1,
+                              act=jax.nn.relu)
         h = jnp.mean(h, axis=(1, 2))
         return h @ p["fc"]["w"] + p["fc"]["b"]
 
@@ -176,12 +177,12 @@ def make_resnet(spec: VisionSpec, widths=(16, 32, 64), blocks_per_stage: int = 1
         return p
 
     def apply(p, x):
-        h = jax.nn.relu(layers.conv2d(p["stem"], x))
+        h = layers.conv2d(p["stem"], x, act=jax.nn.relu)
         for s, w in enumerate(widths):
             for b in range(blocks_per_stage):
                 blk = p[f"s{s}b{b}"]
                 stride = 2 if (s > 0 and b == 0) else 1
-                r = jax.nn.relu(layers.conv2d(blk["c1"], h, stride=stride))
+                r = layers.conv2d(blk["c1"], h, stride=stride, act=jax.nn.relu)
                 r = layers.conv2d(blk["c2"], r)
                 sc = h
                 if "proj" in blk:
@@ -224,13 +225,13 @@ def make_regnet(spec: VisionSpec, widths=(24, 56, 152), depths=(1, 1, 2)) -> Vis
         return p
 
     def apply(p, x):
-        h = jax.nn.relu(layers.conv2d(p["stem"], x, stride=1))
+        h = layers.conv2d(p["stem"], x, stride=1, act=jax.nn.relu)
         for s, (w, dep) in enumerate(zip(widths, depths)):
             for b in range(dep):
                 blk = p[f"s{s}b{b}"]
                 stride = 2 if (s > 0 and b == 0) else 1
-                r = jax.nn.relu(layers.conv2d(blk["c1"], h))
-                r = jax.nn.relu(layers.conv2d(blk["c3"], r, stride=stride))
+                r = layers.conv2d(blk["c1"], h, act=jax.nn.relu)
+                r = layers.conv2d(blk["c3"], r, stride=stride, act=jax.nn.relu)
                 r = layers.conv2d(blk["c2"], r)
                 sc = h
                 if "proj" in blk:

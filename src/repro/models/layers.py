@@ -1,7 +1,7 @@
 """Shared primitive layers: RMSNorm, dense FFN (SwiGLU), embedding, conv."""
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -87,13 +87,28 @@ def conv2d_init(key, cin: int, cout: int, k: int, dtype=jnp.float32) -> Dict:
     return {"w": w, "b": jnp.zeros((cout,), dtype)}
 
 
-def conv2d(p: Dict, x: jax.Array, stride: int = 1, padding: str = "SAME") -> jax.Array:
-    y = jax.lax.conv_general_dilated(
-        x, p["w"].astype(x.dtype),
-        window_strides=(stride, stride), padding=padding,
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-    )
-    return y + p["b"].astype(x.dtype)
+# Named scope of every convolution with its bias add and activation. It
+# adds op_name metadata only (the compiled instructions are unchanged), so a
+# profiler trace can tell the convolutions' time apart inside a round's
+# phases.
+CONV_SCOPE = "cnn.conv"
+
+
+def conv2d(p: Dict, x: jax.Array, stride: int = 1, padding: str = "SAME",
+           act: Optional[Callable[[jax.Array], jax.Array]] = None) -> jax.Array:
+    """``act(conv(x, w) + b)``, all under ``CONV_SCOPE``. The activation
+    belongs inside: with per-client weights under vmap, its backward moves
+    the client axis of the input gradient back with a transpose, which XLA
+    folds into the strided convolution, and the convolution then carries
+    the transpose's op_name."""
+    with jax.named_scope(CONV_SCOPE):
+        y = jax.lax.conv_general_dilated(
+            x, p["w"].astype(x.dtype),
+            window_strides=(stride, stride), padding=padding,
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        )
+        y = y + p["b"].astype(x.dtype)
+        return y if act is None else act(y)
 
 
 def causal_conv1d_init(key, channels: int, width: int, dtype=jnp.float32) -> Dict:
