@@ -1,18 +1,19 @@
-"""Kernel-level benchmark: HBM-pass accounting for the fused Pallas kernels.
+"""Kernel-level benchmark: HBM-pass accounting for the fused reductions.
 
 No wall-clock on CPU — the structural metric is bytes-accessed from
 ``cost_analysis`` of the lowered fused vs unfused reductions, at two levels:
 
-* vector level: ``fused_cosine``'s contract (ONE pass over 2d floats for
+* vector level: the fused triple's contract (ONE pass over 2d floats for
   the (x·y, ||x||², ||y||²) triple instead of three separate reductions);
 * encoder level: the 3SFC objective-evaluation hot path. The seed encoder
   ran ~8 O(d) reduction sweeps plus a materialized s·∇F tree per
-  evaluation; the fused ``tree_stats`` path reads each gradient tree
-  exactly once (≤ 2d·4 bytes + tolerance) and derives Eq. 8's scale,
-  Eq. 9's value and the efficiency cosine as scalar algebra on the triple.
+  evaluation; the fused ``tree_stats`` path reads each leaf of each
+  gradient tree exactly once (2d·4 bytes, no padding) and derives Eq. 8's
+  scale, Eq. 9's value and the efficiency cosine as scalar algebra on the
+  triple.
 
-Also validates ``ops.fused_cosine`` / ``ops.tree_fused_stats`` against
-their oracles across ragged shape sweeps, and emits ``BENCH_kernels.json``
+Also validates ``flat.tree_stats`` on flat vectors and on a ragged tree
+against its oracle, and emits ``BENCH_kernels.json``
 (fused vs unfused bytes + pass counts) so the perf trajectory is tracked
 round over round by ``benchmarks/run.py``.
 """
@@ -28,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import flat
-from repro.kernels import ops, ref
+from repro.kernels import ref
 
 # ragged, non-tile-aligned leaves — sums to d below
 TREE_SHAPES = [(300, 1000), (1025,), (7,), (), (64, 1024), (123, 45)]
@@ -48,6 +49,20 @@ def _bytes(fn, *args) -> float:
     if isinstance(cost, list):
         cost = cost[0]
     return float(cost.get("bytes accessed", 0.0))
+
+
+def _leafwise_stats_bytes(tree) -> int:
+    """HBM bytes ``flat.tree_stats`` reads for a pair of trees shaped and
+    typed like ``tree``, by its contract: each leaf of each operand read
+    once, in its own dtype, with no padding.
+
+    A formula, not a measurement: it does not look at the implementation,
+    so the single-pass gate below restates the contract. What holds the
+    implementation to it is the lowering test in
+    ``tests/test_tree_stats.py`` (no pad, no tree-sized concatenate or
+    reshape) and the v5e compile test of the round."""
+    return 2 * sum(jnp.size(l) * jnp.result_type(l).itemsize
+                   for l in jax.tree_util.tree_leaves(tree))
 
 
 def _seed_encoder_reductions(gw, t):
@@ -71,9 +86,8 @@ def _seed_encoder_reductions(gw, t):
 
 
 def _fused_encoder_reductions(gw, t):
-    """The rewritten path: ONE stats triple per objective evaluation
-    (structural stand-in for the Pallas kernel: same reads, same math)."""
-    st = flat._tree_stats_naive(gw, t)
+    """The rewritten path: ONE stats triple per objective evaluation."""
+    st = flat.tree_stats(gw, t)
     d, gg, tt = st[0], st[1], st[2]
     s = d / (gg + 1e-12)
     cos = jnp.sign(s) * d / (jnp.sqrt(gg) * jnp.sqrt(tt) + 1e-12)
@@ -95,7 +109,7 @@ def run(quick: bool = True, out_dir: str = "experiments/results") -> Dict:
         "unfused_bytes": _bytes(unfused, x, y),
         "fused_oracle_bytes": _bytes(ref.fused_cosine, x, y),
     }
-    print("\n== Kernel pass accounting (fused_cosine, flat vectors) ==")
+    print("\n== Kernel pass accounting (fused triple, flat vectors) ==")
     print(f"  ideal single-pass bytes : {ideal:,}")
     print(f"  unfused (3x vdot)       : {results['unfused_bytes']:,.0f}")
     print(f"  fused oracle            : {results['fused_oracle_bytes']:,.0f}")
@@ -105,17 +119,16 @@ def run(quick: bool = True, out_dir: str = "experiments/results") -> Dict:
     #  * cost_analysis of the lowered jnp stand-ins — what XLA charges on
     #    THIS backend (CPU charges every unfused elementwise intermediate,
     #    so both numbers are inflated; the ratio is still structural);
-    #  * the Pallas block-spec contract — the kernel's grid DMAs exactly two
-    #    (block, 1024) tiles per step, so its TPU HBM traffic is *static*
-    #    (ops.tree_stats_hbm_bytes). That is the single-pass gate.
+    #  * the leafwise contract — each leaf of each tree is read once, with
+    #    no padding (_leafwise_stats_bytes, a formula). That is the
+    #    single-pass gate.
     gw, t = _tree_pair(jax.random.PRNGKey(2))
     d_tree = sum(l.size for l in jax.tree.leaves(gw))
     tree_ideal = 2 * d_tree * 4
     seed_bytes = _bytes(_seed_encoder_reductions, gw, t)
     fused_xla_bytes = _bytes(_fused_encoder_reductions, gw, t)
-    kernel_bytes = ops.tree_stats_hbm_bytes(gw)
-    # tolerance: tail zero padding (<8 rows/chunk by the block plan) + acc
-    tol = 0.02 * tree_ideal + 2 * 8 * 1024 * 4
+    kernel_bytes = _leafwise_stats_bytes(gw)
+    tol = 0.02 * tree_ideal
     results.update({
         "tree_d": d_tree,
         "tree_ideal_bytes": tree_ideal,
@@ -134,14 +147,14 @@ def run(quick: bool = True, out_dir: str = "experiments/results") -> Dict:
     print(f"  ideal (read gw + read t): {tree_ideal:,}")
     print(f"  seed reductions + recon : {seed_bytes:,.0f} "
           f"({results['encoder_seed_passes']:.1f} passes, cost_analysis)")
-    print(f"  fused stand-in (XLA)    : {fused_xla_bytes:,.0f} "
+    print(f"  fused path (XLA)        : {fused_xla_bytes:,.0f} "
           f"({results['encoder_fused_xla_passes']:.1f} passes, cost_analysis; "
           f"{results['encoder_xla_bytes_ratio']:.1f}x less than seed)")
-    print(f"  fused kernel contract   : {kernel_bytes:,.0f} "
-          f"({results['encoder_fused_kernel_passes']:.2f} passes, BlockSpec "
+    print(f"  fused leafwise contract : {kernel_bytes:,.0f} "
+          f"({results['encoder_fused_kernel_passes']:.2f} passes, leaf "
           f"accounting)")
     print(f"  [{'PASS' if results['encoder_fused_single_pass'] else 'FAIL'}] "
-          f"fused stats path <= one read of each tree (+padding tolerance); "
+          f"fused stats path <= one read of each tree (+2% tolerance); "
           f"{results['encoder_bytes_ratio']:.1f}x fewer bytes than the seed "
           f"encoder reductions")
 
@@ -150,15 +163,17 @@ def run(quick: bool = True, out_dir: str = "experiments/results") -> Dict:
     for size in (1000, 131072, 300001):
         xs = jax.random.normal(jax.random.PRNGKey(size), (size,))
         ys = jax.random.normal(jax.random.PRNGKey(size + 1), (size,))
-        got = ops.fused_cosine(xs, ys)
+        got = flat.tree_stats(xs, ys)
         want = ref.fused_cosine(xs, ys)
         checks.append(bool(np.allclose(got, want, rtol=2e-4)))
-    st_got = ops.tree_fused_stats(gw, t)
-    st_want = flat._tree_stats_naive(gw, t)
+    st_got = flat.tree_stats(gw, t)
+    st_want = ref.fused_cosine(
+        *[jnp.concatenate([jnp.ravel(l) for l in jax.tree.leaves(tree)])
+          for tree in (gw, t)])
     checks.append(bool(np.allclose(st_got, st_want, rtol=2e-4)))
     results["allclose"] = all(checks)
     print(f"  [{'PASS' if results['allclose'] else 'FAIL'}] "
-          f"pallas(interpret) == oracle across sizes (vector + tree)")
+          f"leafwise stats == oracle on flat vectors and a ragged tree")
 
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "kernels.json"), "w") as f:

@@ -4,9 +4,10 @@
     python chip_smoke.py --chips 4   # the shard_map client fan-out only
 
 One chip:
-  kernels    the Pallas kernels of the main path at the paper MLP's widths
-             (d = 199,210), compiled (``tpu_custom_call`` in the executable)
-             and checked against ``repro.kernels.ref``;
+  kernels    the Pallas kernels at the paper MLP's width (d = 199,210),
+             compiled (``tpu_custom_call`` in the executable), and the
+             leafwise tree stats and EF update of the 3SFC encode, all
+             checked against ``repro.kernels.ref``;
   3SFC       ``repro.launch.train.main`` at the paper's MLP/MNIST round
              (10 clients, 5 local steps, batch 32) for 20 rounds, float
              wire and codec wire;
@@ -56,8 +57,10 @@ def _max_abs_diff(a, b) -> float:
 
 
 def phase_kernels() -> str:
-    """The main-path kernels, compiled, against the plain-jnp oracles."""
-    from repro.kernels import bitpack, ops, ref
+    """The Pallas kernels, compiled, and the leafwise tree reductions,
+    against the plain-jnp oracles."""
+    from repro.core import flat
+    from repro.kernels import bitpack, ref
     from repro.models.cnn import MNIST_SPEC, make_paper_model
 
     params = make_paper_model("mlp", MNIST_SPEC).init(jax.random.PRNGKey(0))
@@ -73,13 +76,14 @@ def phase_kernels() -> str:
     s = jnp.float32(0.37)
     x = _ravel(c).at[::97].set(0.0)        # exact zeros: the bit = x >= 0 rule
 
-    stats = np.asarray(jax.jit(ops.tree_fused_stats)(a, b))
-    want = np.asarray(ref.fused_cosine(_ravel(a), _ravel(b)))
+    va, vb = _ravel(a), _ravel(b)
+    stats = np.asarray(jax.jit(flat.tree_stats)(a, b))
+    want = np.asarray(ref.fused_cosine(va, vb))
     scale = math.sqrt(want[1] * want[2])
     np.testing.assert_allclose(stats, want, rtol=1e-5, atol=1e-5 * scale)
 
-    ef = np.asarray(_ravel(jax.jit(ops.tree_ef_update)(a, b, s)))
-    ef_want = np.asarray(ref.ef_update(_ravel(a), _ravel(b), s))
+    ef = np.asarray(_ravel(jax.jit(flat.tree_ef_update)(a, b, s)))
+    ef_want = np.asarray(ref.ef_update(va, vb, s))
     np.testing.assert_allclose(ef, ef_want, rtol=1e-6, atol=1e-6)
 
     unpack = jax.jit(lambda w: bitpack.unpack_signs(w, d))
@@ -93,8 +97,6 @@ def phase_kernels() -> str:
                                   np.asarray(jnp.where(x >= 0, 1.0, -1.0)))
 
     compiled = {
-        "fused_cosine": (ops.tree_fused_stats, (a, b)),
-        "ef_update": (ops.tree_ef_update, (a, b, s)),
         "pack_signs": (bitpack.pack_signs, (x,)),
         "unpack_signs": (lambda w: bitpack.unpack_signs(w, d), (words,)),
     }
@@ -102,8 +104,8 @@ def phase_kernels() -> str:
         if "tpu_custom_call" not in jax.jit(f).lower(*args).compile().as_text():
             raise AssertionError(f"{name}: no tpu_custom_call in the "
                                  f"compiled program (kernel not compiled)")
-    return (f"[kernels] d={d}: fused_cosine stats {stats.tolist()} vs ref "
-            f"{want.tolist()}; ef_update max |err| "
+    return (f"[kernels] d={d}: tree stats {stats.tolist()} vs ref "
+            f"{want.tolist()}; EF max |err| "
             f"{float(np.max(np.abs(ef - ef_want)))}; pack/unpack bit-exact "
             f"({words.size} words, {int(np.sum(xn == 0))} exact zeros); "
             f"tpu_custom_call in {len(compiled)}/{len(compiled)} kernels")

@@ -5,8 +5,10 @@ artifacts), then this script; it diffs each fresh artifact against the
 version committed at git HEAD and FAILS (exit 1) on a regression:
 
 * ``BENCH_kernels.json``: any increase in HBM passes per 3SFC objective
-  evaluation (``encoder_fused_kernel_passes``, the BlockSpec contract
-  number — immune to CPU noise), or the single-pass gate flipping false.
+  evaluation (``encoder_fused_kernel_passes``, the leafwise byte
+  contract — a formula on the tree's leaves, immune to CPU noise; the
+  lowering tests hold the implementation to it), or the single-pass gate
+  flipping false.
 * ``BENCH_collectives.json``: any increase in the fused path's per-round
   collective bytes, any drop in the naive/fused wire-bytes ratio beyond
   1% (HLO byte totals are compile-deterministic; the slack only absorbs

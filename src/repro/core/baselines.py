@@ -29,6 +29,8 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import flat
+
 
 class Payload(NamedTuple):
     """Accounted-size stand-in, NOT the wire format. ``floats`` is the
@@ -115,16 +117,14 @@ def reconstruction_stats(vec: jax.Array, recon: jax.Array,
     """(cosine, relative L2 error) of a reconstruction in two fused passes.
 
     The cosine is scalar algebra on the ``(⟨r,v⟩, ||r||², ||v||²)`` triple
-    the Pallas ``fused_cosine`` kernel returns in a single HBM sweep. The
+    ``flat.tree_stats`` returns in a single HBM sweep. The
     error term is deliberately NOT derived from that triple —
     ``||r−v||² = ||r||² − 2⟨r,v⟩ + ||v||²`` cancels catastrophically in f32
     once the error drops below ~3e-4 relative — but from a direct sum over
     the streamed difference (XLA fuses it into one more pass, nothing
     materialized). Two passes total vs the naive route's four.
     """
-    from repro.kernels import ops
-
-    d, rr, vv = ops.fused_cosine(recon, vec)
+    d, rr, vv = flat.tree_stats(recon, vec)
     cos = d / (jnp.sqrt(rr) * jnp.sqrt(vv) + eps)
     sq = jnp.sum(jnp.square(recon.astype(jnp.float32) - vec.astype(jnp.float32)))
     return cos, jnp.sqrt(sq) / (jnp.sqrt(vv) + eps)

@@ -3,6 +3,21 @@
 Compressors in this package operate on *flat* float32 vectors — the
 concatenation of every leaf of the gradient pytree. ``Flattener`` records
 shapes/dtypes once so compress/decompress round-trips are exact.
+
+The tree algebra works leaf by leaf in each leaf's own shape and never
+ravels, concatenates or pads a leaf: on a TPU, raveling a tiled leaf is
+itself a relayout copy. The 3SFC encoder is memory-bound end to end
+(arithmetic intensity ~0.25 FLOP/byte), so the unit of cost is *passes
+over the gradient tree* (d floats, f32):
+
+* ``tree_stats(a, b)`` — ONE pass: reads each leaf of a and of b once
+  (2d·4 bytes) and returns all three partials ``(a·b, ||a||², ||b||²)``,
+  one multi-output reduction per leaf pair. A separate dot + two norms
+  reads each tree twice, and the seed encoder's dot/sqnorm/cosine
+  sequence totalled ~8 passes plus a materialized s·∇F tree.
+* ``tree_ef_update(u, d, s)`` — ONE pass for ``e' = u − s·d`` (read u,
+  read d, write e'), one elementwise fusion per leaf; it never
+  materializes ``s·d`` or the recon tree.
 """
 from __future__ import annotations
 
@@ -67,37 +82,65 @@ def tree_axpy(alpha, x: PyTree, y: PyTree) -> PyTree:
     return jax.tree_util.tree_map(lambda xi, yi: alpha * xi + yi, x, y)
 
 
-def _tree_stats_naive(a: PyTree, b: PyTree) -> jax.Array:
-    """Single-traversal leafwise triple (a·b, ||a||², ||b||²), f32."""
-    def leaf(x, y):
-        xf = jnp.ravel(x).astype(jnp.float32)
-        yf = jnp.ravel(y).astype(jnp.float32)
-        return jnp.stack([jnp.sum(xf * yf), jnp.sum(xf * xf), jnp.sum(yf * yf)])
+def _check_lockstep(a_tree: PyTree, b_tree: PyTree) -> Tuple[list, list]:
+    """Trace-time guard: pairing leaves of trees whose structures or leaf
+    shapes differ would silently mis-pair or broadcast them, so reject both
+    loudly. Returns the two leaf lists."""
+    a_leaves, a_def = jax.tree_util.tree_flatten(a_tree)
+    b_leaves, b_def = jax.tree_util.tree_flatten(b_tree)
+    if a_def != b_def:
+        raise ValueError(
+            f"lockstep tree mismatch: treedefs {a_def} vs {b_def}")
+    a_shapes = [jnp.shape(l) for l in a_leaves]
+    b_shapes = [jnp.shape(l) for l in b_leaves]
+    if a_shapes != b_shapes:
+        raise ValueError(
+            f"lockstep tree mismatch: leaf shapes {a_shapes} vs {b_shapes}")
+    return a_leaves, b_leaves
 
-    parts = jax.tree_util.tree_leaves(jax.tree_util.tree_map(leaf, a, b))
-    return sum(parts) if parts else jnp.zeros((3,), jnp.float32)
 
+def _leaf_stats(x: jax.Array, y: jax.Array) -> jax.Array:
+    """(3,) f32 = [x·y, ||x||², ||y||²] over all axes of one leaf pair.
 
-try:  # Pallas engine; gated so flat algebra survives a missing toolchain.
-    # ImportError ONLY: any other error in the kernels package must surface,
-    # not silently downgrade every reduction to the naive path.
-    from repro.kernels import ops as _kernel_ops
-except ImportError:  # pragma: no cover - exercised only without jax.experimental
-    _kernel_ops = None
+    Elementwise products summed in f32, not ``jnp.dot``: a dot at default
+    precision runs in bf16 on a TPU. The three sums share their operands,
+    so XLA makes them one multi-output reduction that reads each leaf once.
+    """
+    x = x.astype(jnp.float32)
+    y = y.astype(jnp.float32)
+    return jnp.stack([jnp.sum(x * y), jnp.sum(x * x), jnp.sum(y * y)])
 
 
 def tree_stats(a: PyTree, b: PyTree) -> jax.Array:
     """(3,) f32 = [a·b, ||a||², ||b||²] over whole pytrees, ONE HBM pass.
 
-    The primitive every reduction below dispatches through: one streamed
-    read of each tree yields all three partials (see ``kernels.ops.
-    tree_fused_stats`` for the HBM-pass accounting), instead of the 2×
-    traffic of a separate dot + two norms. Differentiable to arbitrary
-    order (custom JVP) and safe under jit/vmap.
+    The primitive every pair reduction below dispatches through: each leaf
+    pair is reduced in its own shape (``_leaf_stats``) and the leaves'
+    triples are added. Mixed-dtype trees are cast to f32 leaf by leaf; a
+    and b must share treedef and leaf shapes. Plain XLA, so differentiable
+    to any order (the 3SFC encoder takes grad-of-grad through it) and safe
+    under jit/vmap.
     """
-    if _kernel_ops is not None:
-        return _kernel_ops.tree_fused_stats(a, b)
-    return _tree_stats_naive(a, b)
+    a_leaves, b_leaves = _check_lockstep(a, b)
+    total = jnp.zeros((3,), jnp.float32)
+    for x, y in zip(a_leaves, b_leaves):
+        total = total + _leaf_stats(x, y)
+    return total
+
+
+def tree_ef_update(u: PyTree, d: PyTree, s: jax.Array) -> PyTree:
+    """EF residual e' = u − s·d over whole pytrees, one pass.
+
+    One fused elementwise op per leaf, in the leaf's own shape: reads u and
+    d once, writes e', and never materializes the scaled ``s·d`` (= recon)
+    tree. Output leaves are f32 in u's shapes.
+    """
+    u_leaves, d_leaves = _check_lockstep(u, d)
+    s = jnp.asarray(s, jnp.float32)
+    return jax.tree_util.tree_unflatten(
+        jax.tree_util.tree_structure(u),
+        [ui.astype(jnp.float32) - s * di.astype(jnp.float32)
+         for ui, di in zip(u_leaves, d_leaves)])
 
 
 def tree_dot(a: PyTree, b: PyTree) -> jax.Array:
@@ -106,9 +149,8 @@ def tree_dot(a: PyTree, b: PyTree) -> jax.Array:
 
 
 def tree_sqnorm(a: PyTree) -> jax.Array:
-    # Deliberately NOT routed through the pair kernel: a single-tree sum of
-    # squares is already one pass; feeding a as both operands would read it
-    # twice from HBM.
+    # Deliberately NOT routed through the pair reduction: a single-tree sum
+    # of squares is already one pass, with one sum per leaf, not three.
     parts = jax.tree_util.tree_map(
         lambda x: jnp.sum(jnp.square(x.astype(jnp.float32))), a
     )

@@ -57,7 +57,6 @@ import numpy as np
 
 from repro.configs.base import CompressorConfig
 from repro.core import flat
-from repro.kernels import ops
 
 PyTree = Any
 
@@ -74,8 +73,8 @@ class TreeCompressed(NamedTuple):
     ``cosine`` (when not None) is the already-computed cos(recon, u), so the
     EF step skips its own ``tree_cosine`` pass; ``direction``/``scale``
     (when not None) factor ``recon = scale · direction``, letting the EF
-    update run as one fused ``e' = u − s·direction`` stream
-    (``kernels.ops.tree_ef_update``) instead of reading the materialized
+    update run as one fused ``e' = u − s·direction`` pass per leaf
+    (``flat.tree_ef_update``) instead of reading the materialized
     recon again. ``wire`` is the method-specific wire payload (what a
     ``repro.comm.codec`` codec serializes and what ``server_decode`` /
     ``server_aggregate`` consume — value/index streams, sign sources, the
@@ -215,7 +214,7 @@ class CompressionStrategy:
         if not self.cfg.error_feedback:
             return e_tree
         if direction is not None:
-            return ops.tree_ef_update(u, direction, scale)
+            return flat.tree_ef_update(u, direction, scale)
         return flat.tree_sub(u, recon)
 
     @staticmethod

@@ -106,8 +106,9 @@ def _objective(
 ) -> Tuple[jax.Array, Tuple[flat.PyTree, jax.Array]]:
     """Eq. 9 value plus aux ``(gw, stats)``.
 
-    ``stats = (⟨gw,t⟩, ||gw||², ||t||²)`` comes from ONE fused HBM pass
-    (``flat.tree_stats``); Eq. 9's cosine, Eq. 8's scale and the reported
+    ``stats = (⟨gw,t⟩, ||gw||², ||t||²)`` comes from ONE HBM pass
+    (``flat.tree_stats``: one multi-output reduction per leaf pair, in the
+    leaf's own layout); Eq. 9's cosine, Eq. 8's scale and the reported
     compression efficiency are all scalar algebra on this same triple, so
     each objective evaluation reads the gradient trees exactly once.
     """
@@ -132,7 +133,8 @@ class EncodeResult(NamedTuple):
         """s · ∇_w F(D_syn, w^t) — what the server sees (Eq. 10).
 
         Materialized on demand: EF paths that only need ``e' = u − s·gw``
-        (``kernels.ops.tree_ef_update``) never instantiate this tree.
+        (``flat.tree_ef_update``, one elementwise pass per leaf)
+        never instantiate this tree.
         """
         return flat.tree_scale(self.gw, self.s)
 
@@ -157,11 +159,12 @@ def encode(
     normalized variant is markedly more robust across the 10 assigned
     architectures (recorded as a beyond-paper change in DESIGN.md).
 
-    Perf: every objective evaluation reduces the gradient trees exactly once
-    (the fused ``flat.tree_stats`` triple); s, the efficiency cosine and the
-    Eq. 9 value are scalar algebra on that triple, and the reconstruction is
-    returned factored as (gw, s) so EF consumers can stream
-    ``e' = u − s·gw`` without materializing s·gw (see ``EncodeResult.recon``).
+    Perf: every objective evaluation reduces the gradient trees exactly once,
+    leaf by leaf in each leaf's own layout (the fused ``flat.tree_stats``
+    triple); s, the efficiency cosine and the Eq. 9 value are scalar algebra
+    on that triple, and the reconstruction is returned factored as (gw, s)
+    so EF consumers compute ``e' = u − s·gw`` in one pass without
+    materializing s·gw (see ``EncodeResult.recon``).
     """
 
     def obj_aux(syn: SynData):

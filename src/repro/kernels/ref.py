@@ -1,4 +1,5 @@
-"""Pure-jnp oracles for every kernel (the correctness contract)."""
+"""Pure-jnp oracles for every kernel and for the 3SFC tree reductions
+(the correctness contract)."""
 from __future__ import annotations
 
 from typing import Tuple

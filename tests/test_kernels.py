@@ -1,9 +1,11 @@
-"""Pallas kernels vs ref.py oracles: shape x dtype sweeps (interpret mode)."""
+"""Pallas kernels (interpret mode) and the leafwise fused reductions vs
+ref.py oracles: shape x dtype sweeps."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import flat
 from repro.kernels import ops, ref
 from repro.models import ssm as ssm_mod
 
@@ -11,12 +13,16 @@ SIZES = [1, 1000, 4096, 131072, 300001]
 DTYPES = [jnp.float32, jnp.bfloat16]
 
 
+# The fused (x·y, ||x||², ||y||²) triple and the EF axpy need no kernel:
+# they run leafwise in plain XLA (core.flat), and a flat vector is one leaf.
+
+
 @pytest.mark.parametrize("n", SIZES)
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_fused_cosine(n, dtype):
     x = jax.random.normal(jax.random.PRNGKey(n), (n,), dtype)
     y = jax.random.normal(jax.random.PRNGKey(n + 1), (n,), dtype)
-    got = ops.fused_cosine(x, y)
+    got = flat.tree_stats(x, y)
     want = ref.fused_cosine(x, y)
     np.testing.assert_allclose(got, want, rtol=5e-3 if dtype == jnp.bfloat16 else 2e-4)
 
@@ -25,7 +31,7 @@ def test_fused_cosine(n, dtype):
 def test_ef_update(n):
     u = jax.random.normal(jax.random.PRNGKey(n), (n,))
     d = jax.random.normal(jax.random.PRNGKey(n + 1), (n,))
-    got = ops.ef_update(u, d, jnp.float32(0.37))
+    got = flat.tree_ef_update(u, d, jnp.float32(0.37))
     want = ref.ef_update(u, d, jnp.float32(0.37))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
     assert got.shape == u.shape

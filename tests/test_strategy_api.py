@@ -175,8 +175,12 @@ def test_toy_strategy_shard_map_codec(multidev_scenario):
     """The toy method over the sharded fan-out in wire mode must be bitwise
     the vmap float oracle on a width-matched mesh and bitwise the float
     wire on the 8-way client axis (its codec is lossless); the 8-way run
-    agrees with the vmap oracle to two roundings of the largest weight."""
+    agrees with the vmap oracle to two roundings of the largest weight, and
+    its efficiency cosines to two ulps."""
     multidev_scenario("shard_codec", file="tests/test_strategy_api.py")
+
+
+METRICS = ("loss", "cosine", "payload_floats", "update_norm")
 
 
 def scenario_shard_codec():
@@ -202,14 +206,21 @@ def scenario_shard_codec():
 
     # width-matched mesh (client axis 1: each device runs all 8 clients,
     # as vmap does): the shard_map + codec plumbing is bitwise transparent
-    s_m, _ = shard(make_mesh((1, 8), ("data", "model")))
+    s_m, m_m = shard(make_mesh((1, 8), ("data", "model")))
     for a, b in zip(leaves(s_f), leaves(s_m)):
         np.testing.assert_array_equal(a, b)
+    for f in METRICS:
+        np.testing.assert_array_equal(np.asarray(getattr(m_f, f)),
+                                      np.asarray(getattr(m_m, f)))
     # 8-way client axis (1 client per device): the codec is bitwise the
     # float wire at that lowering too
     s_w, m_w = shard(mesh)
-    for a, b in zip(leaves(shard(mesh, wire="float")[0]), leaves(s_w)):
+    s_wf, m_wf = shard(mesh, wire="float")
+    for a, b in zip(leaves(s_wf), leaves(s_w)):
         np.testing.assert_array_equal(a, b)
+    for f in METRICS:
+        np.testing.assert_array_equal(np.asarray(getattr(m_wf, f)),
+                                      np.asarray(getattr(m_w, f)))
     # against the vmap oracle, XLA:CPU lowers the clients' local training
     # per width: batched dots f32[8,8,200] under vmap, plain f32[8,200]
     # dots at one client per device. The local weights w_K then differ by
@@ -222,9 +233,17 @@ def scenario_shard_codec():
     tol = 2 * float(np.spacing(np.float32(w_max)))
     for a, b in zip(leaves(s_f), leaves(s_w)):
         np.testing.assert_allclose(a, b, rtol=0, atol=tol)
-    for f in ("loss", "cosine", "payload_floats", "update_norm"):
+    # The metrics that do not read u stay bitwise against the vmap oracle.
+    # The efficiency cosine is a function of u, which differs by one
+    # rounding here, so it may round to a neighbouring f32 (observed: one
+    # ulp on one client); the bound is two ulps of the largest cosine. It
+    # is bitwise at the width-matched mesh and against the float wire above.
+    for f in ("loss", "payload_floats", "update_norm"):
         np.testing.assert_array_equal(np.asarray(getattr(m_f, f)),
                                       np.asarray(getattr(m_w, f)))
+    cos_f, cos_w = np.asarray(m_f.cosine), np.asarray(m_w.cosine)
+    cos_tol = 2 * float(np.spacing(np.float32(np.max(np.abs(cos_f)))))
+    np.testing.assert_allclose(cos_f, cos_w, rtol=0, atol=cos_tol)
     assert float(np.asarray(m_w.wire_bytes_up)) == codec.nbytes
     print("ok toy shard_codec")
 

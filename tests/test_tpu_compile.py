@@ -3,8 +3,10 @@
 The chip's compiler (Mosaic for the Pallas kernels, XLA:TPU for the rest)
 refuses programs that interpret mode runs happily: scalar stores to VMEM,
 unsigned reductions and casts, lane reshapes. Each test here AOT-compiles
-for one chip of a described ``v5e:2x2`` topology and asserts the kernel is
-in the executable as a ``tpu_custom_call`` — no interpreter. Nothing runs.
+for one chip of a described ``v5e:2x2`` topology and asserts each kernel
+it expects is in the executable as a ``tpu_custom_call`` — no interpreter —
+or, for the 3SFC float round, that no kernel and no packed copy of the
+tree is. Nothing runs.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -19,10 +21,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import bitpack, ops
-from repro.kernels.ef_update import ef_update_2d
-from repro.kernels.fused_cosine import LANES, fused_cosine_2d
 
 MLP_D = 199_210          # the paper MLP's parameter count (784-200-200-10)
+CONVNET_D = 390_986      # the ConvNet's parameter count (widths 32-64-128-256)
 
 
 @pytest.fixture(scope="module")
@@ -57,16 +58,6 @@ def _compiled_text(f, *args) -> str:
 def _kernel_case(name, chip):
     spec = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=chip)
-    if name == "fused_cosine":
-        br, rows = ops._plan_rows(MLP_D, 128)
-        return (lambda x, y: fused_cosine_2d(x, y, block_rows=br,
-                                             interpret=False),
-                spec((rows, LANES)), spec((rows, LANES)))
-    if name == "ef_update":
-        br, rows = ops._plan_rows(MLP_D, 256)
-        return (lambda u, d, s: ef_update_2d(u, d, s, block_rows=br,
-                                             interpret=False),
-                spec((rows, LANES)), spec((rows, LANES)), spec(()))
     tile = bitpack.BLOCK_ROWS * bitpack.PACK_LANES
     rows = -(-MLP_D // tile) * bitpack.BLOCK_ROWS
     if name == "pack_signs":
@@ -76,21 +67,25 @@ def _kernel_case(name, chip):
             spec((rows, bitpack.WORD_LANES), jnp.uint32))
 
 
-@pytest.mark.parametrize("kernel", ["fused_cosine", "ef_update",
-                                    "pack_signs", "unpack_signs"])
+@pytest.mark.parametrize("kernel", ["pack_signs", "unpack_signs"])
 def test_main_path_kernel_compiles_for_v5e(kernel, one_chip,
                                            no_compile_cache):
     f, *args = _kernel_case(kernel, one_chip)
     assert "tpu_custom_call" in _compiled_text(f, *args)
 
 
-@pytest.mark.parametrize("kind,wire", [("threesfc", "float"),
-                                       ("signsgd", "codec")])
-def test_vmap_round_compiles_for_v5e(kind, wire, one_chip, no_compile_cache,
-                                     monkeypatch):
-    """The whole vmapped round at the paper's MLP/MNIST widths (10 clients,
-    5 local steps, batch 32) — the kernels under the client vmap, as the
-    round batches them."""
+@pytest.mark.parametrize("kind,wire,model", [
+    pytest.param("threesfc", "float", "mlp", id="threesfc-float"),
+    pytest.param("signsgd", "codec", "mlp", id="signsgd-codec"),
+    pytest.param("threesfc", "float", "convnet", id="threesfc-float-convnet"),
+])
+def test_vmap_round_compiles_for_v5e(kind, wire, model, one_chip,
+                                     no_compile_cache, monkeypatch):
+    """The whole vmapped round (10 clients, 5 local steps, batch 32) of the
+    paper's MLP on MNIST, or of the ConvNet on CIFAR-10. The codec round
+    holds its bitpack kernels under the client vmap; the 3SFC float round
+    reduces each leaf in its own layout, so it holds no kernel, no
+    per-client d-vector and no copy padded to 1024 lanes."""
     from repro.configs.base import FLConfig
     from repro.configs.run import RunConfig
     from repro.core import flat
@@ -98,23 +93,25 @@ def test_vmap_round_compiles_for_v5e(kind, wire, one_chip, no_compile_cache,
     from repro.fl.budget import matched_compressors
     from repro.fl.round import build_fl_round, fl_init
     from repro.models.build import vision_syn_spec
-    from repro.models.cnn import MNIST_SPEC, make_paper_model
+    from repro.models.cnn import CIFAR10_SPEC, MNIST_SPEC, make_paper_model
 
     # the backend seen here is the CPU's; steer the one interpret switch
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     n, k, b = 10, 5, 32
-    model = make_paper_model("mlp", MNIST_SPEC)
-    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    assert flat.tree_size(params) == MLP_D
-    comp = matched_compressors("mlp", MNIST_SPEC, MLP_D)[kind]
-    strategy = make_strategy(comp, loss_fn=model.syn_loss,
-                             syn_spec=vision_syn_spec(MNIST_SPEC, comp),
+    spec, d = {"mlp": (MNIST_SPEC, MLP_D),
+               "convnet": (CIFAR10_SPEC, CONVNET_D)}[model]
+    net = make_paper_model(model, spec)
+    params = jax.eval_shape(net.init, jax.random.PRNGKey(0))
+    assert flat.tree_size(params) == d
+    comp = matched_compressors(model, spec, d)[kind]
+    strategy = make_strategy(comp, loss_fn=net.syn_loss,
+                             syn_spec=vision_syn_spec(spec, comp),
                              local_lr=0.01)
     run = RunConfig(fl=FLConfig(num_clients=n, local_steps=k, local_lr=0.01,
                                 local_batch=b, compressor=comp), wire=wire)
     codec = strategy.wire_codec(params, policy=run.wire_policy) \
         if wire == "codec" else None
-    round_fn = build_fl_round(model.loss, strategy, run, codec=codec)
+    round_fn = build_fl_round(net.loss, strategy, run, codec=codec)
 
     def on_chip(tree):
         return jax.tree_util.tree_map(
@@ -123,8 +120,14 @@ def test_vmap_round_compiles_for_v5e(kind, wire, one_chip, no_compile_cache,
 
     state = on_chip(jax.eval_shape(lambda p: fl_init(p, n, strategy), params))
     batches = on_chip({
-        "x": jax.ShapeDtypeStruct((n, k, b, *MNIST_SPEC.input_shape),
+        "x": jax.ShapeDtypeStruct((n, k, b, *spec.input_shape),
                                   jnp.float32),
         "y": jax.ShapeDtypeStruct((n, k, b), jnp.int32)})
     key = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
-    assert "tpu_custom_call" in _compiled_text(round_fn, state, batches, key)
+    text = _compiled_text(round_fn, state, batches, key)
+    if wire == "codec":
+        assert "tpu_custom_call" in text
+    else:
+        assert "tpu_custom_call" not in text
+        assert f"f32[{n},{d}]" not in text
+        assert ",1024]" not in text
