@@ -25,9 +25,8 @@ measured bytes, not a docstring. Gated:
 
 The 8-device mesh needs ``--xla_force_host_platform_device_count=8`` BEFORE
 jax initializes, so the measurement runs in a child process (``--child``)
-and the orchestrator-facing ``run()`` parses its JSON. Emits
-``BENCH_collectives.json`` (repo root) + ``experiments/results/
-collectives.json`` for the ``scripts/check_bench.py`` trajectory gate.
+and the orchestrator-facing ``run()`` parses its JSON. Writes
+``experiments/results/collectives.json``.
 """
 from __future__ import annotations
 
@@ -38,22 +37,9 @@ import subprocess
 import sys
 from typing import Dict
 
+from repro.launch.mesh import multidev_env
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def multidev_env() -> Dict[str, str]:
-    """Child environment for forced-8-device host-CPU runs: the XLA device
-    flag (must precede jax init), CPU platform pin, and src+repo on
-    PYTHONPATH. Shared with the tests' ``multidev`` subprocess runner
-    (tests/conftest.py) so the recipe lives in one place."""
-    env = os.environ.copy()
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8").strip()
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(REPO, "src"), REPO]
-        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-    return env
 
 
 N_CLIENTS = 8                      # divisible over the 8-device client axis
@@ -291,8 +277,6 @@ def run(quick: bool = True, out_dir: str = "experiments/results") -> Dict:
 
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "collectives.json"), "w") as f:
-        json.dump(results, f, indent=2)
-    with open(os.path.join(REPO, "BENCH_collectives.json"), "w") as f:
         json.dump(results, f, indent=2)
     return results
 

@@ -18,9 +18,8 @@ staleness k in {0, 2} (k=2 adds 40% stragglers, late payloads weighted
 1/(1+delay)) — is recorded for the table; only the 30%-dropout column is
 gated (50% dropout and staleness are reported, not promised). Fault
 schedules are a pure function of (fault_seed, round), so every cell is
-deterministic — ``--quick`` differs from ``--full`` only in rounds. Emits
-``BENCH_faults.json`` (repo root) + ``experiments/results/faults.json``
-for the ``scripts/check_bench.py`` trajectory gate.
+deterministic — ``--quick`` differs from ``--full`` only in rounds. Writes
+``experiments/results/faults.json``.
 """
 from __future__ import annotations
 
@@ -31,8 +30,6 @@ from typing import Dict, Optional
 
 import jax
 import numpy as np
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 N_CLIENTS = 8
 LOCAL_STEPS, LOCAL_BATCH = 2, 16
@@ -205,8 +202,6 @@ def run(quick: bool = True, out_dir: str = "experiments/results") -> Dict:
 
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "faults.json"), "w") as f:
-        json.dump(results, f, indent=2)
-    with open(os.path.join(REPO, "BENCH_faults.json"), "w") as f:
         json.dump(results, f, indent=2)
     return results
 

@@ -1,7 +1,7 @@
 """Chaos-kill gate: durable recovery points, bitwise resume, elastic rejoin.
 
-Four scenarios, four absolute gates (emitted as ``pass_*`` flags for
-``scripts/check_bench.py``):
+Four scenarios, four absolute gates (emitted as ``pass_*`` flags; a false
+one fails ``benchmarks.run``):
 
 * **server SIGKILL + resume** (``pass_bitwise_resume``, socket half): a
   ``repro.launch.train`` socket run (mlp/mnist/stc, 2 workers,
@@ -29,8 +29,8 @@ Four scenarios, four absolute gates (emitted as ``pass_*`` flags for
   index rename — leaves the PREVIOUS recovery point committed and
   loadable, and a retried save over the debris succeeds.
 
-Deterministic except wall clock; ``--quick`` == ``--full``. Emits
-``BENCH_recovery.json`` (repo root) + ``experiments/results/recovery.json``.
+Deterministic except wall clock; ``--quick`` == ``--full``. Writes
+``experiments/results/recovery.json``.
 """
 from __future__ import annotations
 
@@ -547,8 +547,6 @@ def run(quick: bool = True, out_dir: str = "experiments/results") -> Dict:
 
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "recovery.json"), "w") as f:
-        json.dump(results, f, indent=2)
-    with open(os.path.join(REPO, "BENCH_recovery.json"), "w") as f:
         json.dump(results, f, indent=2)
     return results
 

@@ -9,8 +9,9 @@ the in-process oracle. Four gates:
   ``N * codec.nbytes`` exactly on settled rounds (control traffic —
   heartbeats, ACKs, length prefixes — is accounted separately as
   overhead), at BOTH the tiny/stc scenario and the paper mlp/mnist
-  3SFC config; the 8-client total must equal ``BENCH_wire.json``'s
-  measured ``channel.uplink_bytes_per_round`` (same codec, so the live
+  3SFC config; the 8-client total must equal the wire record's
+  (``experiments/results/wire.json``) measured
+  ``channel.uplink_bytes_per_round`` (same codec, so the live
   wire carries not one byte more than the accounted one);
 * **socket bitwise**: a live multi-process run — including injected frame
   drops (``rx_filter``) and a SIGKILLed worker — produces params, per-
@@ -33,9 +34,8 @@ the in-process oracle. Four gates:
 Worker round-0 jit compilation happens inside the live round, so every
 scenario warms round 0 under a generous deadline and gates only the
 settled rounds after it. Deterministic except the wall-clock gate
-(slack-padded); ``--quick`` == ``--full``. Emits ``BENCH_transport.json``
-(repo root) + ``experiments/results/transport.json`` for
-``scripts/check_bench.py``.
+(slack-padded); ``--quick`` == ``--full``. Writes
+``experiments/results/transport.json``.
 """
 from __future__ import annotations
 
@@ -59,7 +59,7 @@ DROPS = {(2, 1), (3, 0)}             # (round, cid) frames the wire eats
 KILL_CID, KILL_AFTER_ROUND = 2, 3    # SIGKILL between rounds 3 and 4
 CONS_ROUND, CONS_CID = 3, 0          # conservation checked on this drop
 
-# -- paper-shape scenario (byte gate vs BENCH_wire) -------------------------
+# -- paper-shape scenario (byte gate vs the wire record) --------------------
 MLP_N = 2                            # live workers; scaled to the 8-client
 MLP_MEASURED_ROUNDS = 2              # total by messages (frames are i.i.d.
 MLP_TRAIN = 256                      # in size: codec.nbytes each)
@@ -346,15 +346,15 @@ def _tiny_scenarios() -> Dict:
 def _mlp_bytes_scenario() -> Dict:
     """Paper-shape byte gate: live mlp/mnist 3SFC frames over the socket
     must bill exactly ``codec.nbytes`` per message — the same measured
-    bytes BENCH_wire accounts — so the 8-client round total equals
-    ``BENCH_wire.json``'s ``channel.uplink_bytes_per_round``."""
+    bytes bench_wire accounts — so the 8-client round total equals the
+    wire record's ``channel.uplink_bytes_per_round``."""
     from repro.configs.base import FLConfig
     from repro.configs.run import RunConfig
     from repro.core import flat
     from repro.fl.budget import matched_compressors
     from repro.models.cnn import MNIST_SPEC
 
-    # the exact BENCH_wire codec config (syn_batch-matched 3SFC)
+    # the exact bench_wire codec config (syn_batch-matched 3SFC)
     from repro.models.cnn import make_paper_model
     model0 = make_paper_model("mlp", MNIST_SPEC)
     d = flat.tree_size(model0.init(jax.random.PRNGKey(0)))
@@ -375,7 +375,7 @@ def _mlp_bytes_scenario() -> Dict:
     measured = [int(r["bytes_up"]) for r in history[1:]]
     per_msg = measured[0] // MLP_N if measured else 0
     wire_ref: Optional[Dict] = None
-    wire_path = os.path.join(REPO, "BENCH_wire.json")
+    wire_path = os.path.join(REPO, "experiments", "results", "wire.json")
     if os.path.exists(wire_path):
         with open(wire_path) as f:
             wire = json.load(f)
@@ -475,7 +475,7 @@ def run(quick: bool = True, out_dir: str = "experiments/results") -> Dict:
           "in-process oracle...")
     faulted = _tiny_scenarios()
     print("live mlp/mnist 3SFC frames over the socket (byte gate vs "
-          "BENCH_wire)...")
+          "the wire record)...")
     bytes_mlp = _mlp_bytes_scenario()
     print(f"straggle: worker {STRAGGLE_CID} sleeps {STRAGGLE_S:.1f}s/round "
           f"under a {STRAGGLE_DEADLINE_S:.2f}s deadline...")
@@ -503,7 +503,7 @@ def run(quick: bool = True, out_dir: str = "experiments/results") -> Dict:
           f"wire bills exactly N*nbytes: tiny "
           f"{t['settled_null_round_bytes']}/{t['settled_null_round_expected']}"
           f" B, mlp {m['measured_round_bytes']} B "
-          f"(n8 total {m['n8_round_bytes']} B == BENCH_wire "
+          f"(n8 total {m['n8_round_bytes']} B == wire record "
           f"{(m['wire_reference'] or {}).get('uplink_bytes_per_round')})")
     print(f"  [{'PASS' if results['pass_socket_bitwise'] else 'FAIL'}] "
           f"live faulted run bitwise == oracle: masks "
@@ -523,8 +523,6 @@ def run(quick: bool = True, out_dir: str = "experiments/results") -> Dict:
 
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "transport.json"), "w") as f:
-        json.dump(results, f, indent=2)
-    with open(os.path.join(REPO, "BENCH_transport.json"), "w") as f:
         json.dump(results, f, indent=2)
     return results
 

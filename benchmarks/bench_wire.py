@@ -26,9 +26,8 @@ MLP/MNIST shapes (d = 199,210) and measured. Gated:
 
 Also exercises ``comm.channel.InProcessChannel``: one round's frames move
 client->server through it and the uplink counters must bill exactly
-N · nbytes. Deterministic end to end — ``--quick`` == ``--full``. Emits
-``BENCH_wire.json`` (repo root) + ``experiments/results/wire.json`` for the
-``scripts/check_bench.py`` trajectory gate.
+N · nbytes. Deterministic end to end — ``--quick`` == ``--full``. Writes
+``experiments/results/wire.json``.
 """
 from __future__ import annotations
 
@@ -40,8 +39,6 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 N_CLIENTS = 8
 LOCAL_STEPS, LOCAL_BATCH = 5, 32       # paper MLP/MNIST round shape
@@ -317,8 +314,6 @@ def run(quick: bool = True, out_dir: str = "experiments/results") -> Dict:
 
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "wire.json"), "w") as f:
-        json.dump(results, f, indent=2)
-    with open(os.path.join(REPO, "BENCH_wire.json"), "w") as f:
         json.dump(results, f, indent=2)
     return results
 

@@ -1,12 +1,13 @@
-"""Benchmark orchestrator — one module per paper table/figure.
+"""CPU experiment orchestrator: the paper's tables and figures, and the
+correctness drivers (collectives, wire, faults, transport, recovery,
+observability). Each writes ``experiments/results/<name>.json``; speed is
+the chip benchmark's (``BENCHMARK.json``, ``bench/``).
 
     PYTHONPATH=src python -m benchmarks.run            # quick (CPU-friendly)
     PYTHONPATH=src python -m benchmarks.run --full     # paper-scale rounds
     PYTHONPATH=src python -m benchmarks.run --only table2,fig7
 
-Dry-run/roofline tables are produced separately (they need the 512-device
-XLA flag): ``python -m repro.launch.dryrun --all`` then
-``python -m benchmarks.roofline_table``.
+Exits 1, naming the driver, when a driver's result has a false ``"pass"``.
 """
 from __future__ import annotations
 
@@ -15,9 +16,8 @@ import sys
 import time
 
 from benchmarks import (bench_collectives, bench_faults, bench_fedsynth,
-                        bench_fig1, bench_fig7, bench_kernels,
-                        bench_observability, bench_recovery,
-                        bench_round_engine, bench_ssweep, bench_table2,
+                        bench_fig1, bench_fig7, bench_observability,
+                        bench_recovery, bench_ssweep, bench_table2,
                         bench_table3, bench_table4, bench_transport,
                         bench_wire)
 
@@ -29,14 +29,12 @@ BENCHES = {
     "fig7": bench_fig7.run,          # compression efficiency curves
     "fedsynth": bench_fedsynth.run,  # table1 + fig2/3 collapse
     "ssweep": bench_ssweep.run,      # encoder-iteration knob (Algorithm 1 S)
-    "kernels": bench_kernels.run,    # fused-kernel pass accounting
-    "round_engine": bench_round_engine.run,  # scanned engine vs python loop
     "collectives": bench_collectives.run,    # sharded fan-out wire bytes
     "wire": bench_wire.run,                  # serialized codec bytes + parity
     "faults": bench_faults.run,              # dropout/staleness degradation
     "transport": bench_transport.run,        # live socket rounds vs oracle
     "recovery": bench_recovery.run,          # chaos-kill: resume + rejoin
-    "observability": bench_observability.run,  # trace overhead + completeness
+    "observability": bench_observability.run,  # trace completeness + bytes
 }
 
 
@@ -66,10 +64,15 @@ def main(argv=None):
         print(f"valid names: {', '.join(BENCHES)}", file=sys.stderr)
         sys.exit(2)
     t0 = time.time()
+    failed = []
     for name in names:
         print(f"\n######## {name} " + "#" * (70 - len(name)))
-        BENCHES[name](quick=not args.full)
+        if not BENCHES[name](quick=not args.full).get("pass", True):
+            failed.append(name)
     print(f"\nall benchmarks done in {time.time()-t0:.0f}s")
+    if failed:
+        print(f"failed gate(s): {', '.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

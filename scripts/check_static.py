@@ -5,7 +5,7 @@ binary exists) ruff with the repo's pinned ``pyproject.toml`` rule set:
 
 * **IR contracts** — compiles every constructible
   strategy × fan-out × wire × fused × faulted round configuration at tiny
-  shapes in a forced-8-device child (the ``bench_collectives`` recipe)
+  shapes in a forced-8-device child (``repro.launch.mesh.multidev_env``)
   and checks the five ``repro.analysis.contracts`` rules against the
   optimized HLO.
 * **Repo lint** — the four AST rules of ``repro.analysis.lint`` over
@@ -17,9 +17,8 @@ binary exists) ruff with the repo's pinned ``pyproject.toml`` rule set:
   ``available: false`` and the layer is skipped (never silently green:
   the artifact says so).
 
-Emits ``BENCH_static.json`` (repo root, diffed by
-``scripts/check_bench.py``: violations must stay 0, rule and config
-coverage may only grow) and exits 1 on any violation.
+The full run writes ``experiments/results/static.json``; every run exits 1
+on any violation.
 
     python scripts/check_static.py            # full gate (~2 min)
     python scripts/check_static.py --skip-ir  # AST layers only (seconds)
@@ -44,7 +43,7 @@ IR_CHILD_TIMEOUT_S = 1200
 def run_ir_layer() -> Dict:
     """The contract matrix needs >=4 XLA devices before jax initializes,
     so it runs in a child under the shared forced-8-device recipe."""
-    from benchmarks.bench_collectives import multidev_env
+    from repro.launch.mesh import multidev_env
     p = subprocess.run([sys.executable, "-m", "repro.analysis.ir"],
                        env=multidev_env(), cwd=REPO, capture_output=True,
                        text=True, timeout=IR_CHILD_TIMEOUT_S)
@@ -132,22 +131,19 @@ def main(argv=None) -> int:
     report["pass"] = report["violations"] == 0
 
     if args.skip_ir:
-        # a partial run must never overwrite the gated artifact with one
-        # whose coverage collapsed — check_bench would flag the shrink,
-        # but the committed artifact should always be the full gate
+        # a partial run never overwrites the record of the full gate
         print(f"\ncheck_static (partial, --skip-ir): "
               f"{report['rules_evaluated']} rules, "
               f"{report['violations']} violation(s); artifact not written")
     else:
         out_dir = os.path.join(REPO, "experiments", "results")
         os.makedirs(out_dir, exist_ok=True)
-        for path in (os.path.join(REPO, "BENCH_static.json"),
-                     os.path.join(out_dir, "static.json")):
-            with open(path, "w") as f:
-                json.dump(report, f, indent=2)
+        with open(os.path.join(out_dir, "static.json"), "w") as f:
+            json.dump(report, f, indent=2)
         print(f"\ncheck_static: {report['configs_evaluated']} IR configs, "
               f"{report['rules_evaluated']} rule evaluations, "
-              f"{report['violations']} violation(s) -> BENCH_static.json")
+              f"{report['violations']} violation(s) -> "
+              f"experiments/results/static.json")
     return 0 if report["pass"] else 1
 
 

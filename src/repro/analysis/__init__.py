@@ -1,7 +1,7 @@
 """Compile-time contract checking: IR invariants, repo lints, protocol.
 
 Three layers, one driver (``scripts/check_static.py`` →
-``BENCH_static.json``):
+``experiments/results/static.json``):
 
 * ``contracts``/``ir`` — declarative ``Contract`` rules over the compiled
   HLO of every constructible ``build_fl_round`` configuration;

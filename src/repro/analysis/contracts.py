@@ -67,7 +67,7 @@ def encode_region_collectives(hlo_text: str) -> List[H.CollectiveInstr]:
 
 def collective_summary(hlo_text: str) -> Dict[str, Any]:
     """Per-module collective bill + encode-region census, the record shape
-    ``BENCH_collectives.json`` carries per compiled round."""
+    ``experiments/results/collectives.json`` carries per compiled round."""
     cols = H.collectives(hlo_text)
     by_kind: Dict[str, float] = {}
     for c in cols:
@@ -273,7 +273,7 @@ def run_contracts(artifacts: List[RoundArtifact],
                   ) -> Dict[str, Any]:
     """Evaluate every contract against every artifact it applies to.
 
-    Returns the ``BENCH_static.json`` IR stanza: per-contract evaluation
+    Returns the static record's IR stanza: per-contract evaluation
     counts + violation messages, the covered config labels, and totals.
     """
     per: Dict[str, Dict[str, Any]] = {}

@@ -13,7 +13,7 @@ precisely the space a user can construct, no more and no less.
 EF state donated, and packages the optimized HLO plus the config-derived
 expectations into a ``contracts.RoundArtifact``. shard_map points need a
 ≥4-device runtime, so ``python -m repro.analysis.ir`` is run as a child
-under ``benchmarks.bench_collectives.multidev_env()`` (the forced-8-device
+under ``repro.launch.mesh.multidev_env()`` (the forced-8-device
 host-CPU recipe) and prints the ``contracts.run_contracts`` report as JSON
 — the driver (``scripts/check_static.py``) never ships HLO text across the
 process boundary, only the verdicts.
@@ -110,7 +110,7 @@ def build_round_artifact(config: Dict[str, Any],
     if shard and ctx["mesh"] is None:
         raise RuntimeError(
             "shard_map config needs a >=4-device runtime "
-            "(run via benchmarks.bench_collectives.multidev_env())")
+            "(run via repro.launch.mesh.multidev_env())")
 
     ccfg = CompressorConfig(kind=kind, keep_ratio=0.25, syn_steps=2,
                             syn_lr=0.1,

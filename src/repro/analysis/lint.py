@@ -436,7 +436,7 @@ RULES = (
 
 
 def run_lint(files: Optional[Dict[str, str]] = None) -> Dict[str, Any]:
-    """Run every lint rule; returns the ``BENCH_static.json`` lint stanza."""
+    """Run every lint rule; returns the static record's lint stanza."""
     if files is None:
         files = collect_sources()
     trees = _parse_all(files)

@@ -355,7 +355,7 @@ def check_races(transport_src: Optional[str] = None,
 def run_protocol(transport_src: Optional[str] = None,
                  worker_src: Optional[str] = None,
                  engine_src: Optional[str] = None) -> Dict[str, Any]:
-    """Both analyses; returns the ``BENCH_static.json`` protocol stanza."""
+    """Both analyses; returns the static record's protocol stanza."""
     g_eval, g_viol = check_protocol(transport_src, worker_src)
     r_eval, r_viol = check_races(transport_src, engine_src)
     table = build_transitions(transport_src, worker_src)

@@ -21,7 +21,7 @@ RESEND, heartbeats, metrics, EF dumps) are protocol overhead, billed into
 ``overhead_up``/``overhead_down`` counters; only data-frame bytes land in
 the ``LinkStats`` buckets, so "uplink bytes per round" means exactly what
 it means on the in-process channel: serialized codec frames
-(``BENCH_transport`` gates the two equal).
+(``benchmarks/bench_transport`` gates the two equal).
 
 Round lifecycle (server side, driven by ``repro.fl.engine.LiveRoundLoop``)
 --------------------------------------------------------------------------

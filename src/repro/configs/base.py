@@ -1,7 +1,7 @@
 """Config dataclasses + registry for repro.
 
 Every assigned architecture gets a module in this package defining
-``CONFIG: ModelConfig`` (full size, dry-run only) and ``smoke_config()``
+``CONFIG: ModelConfig`` (full size) and ``smoke_config()``
 (reduced variant for CPU tests). ``get_config(arch_id)`` resolves dash or
 underscore ids.
 """

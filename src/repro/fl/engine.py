@@ -207,7 +207,7 @@ def token_batcher(tokens: np.ndarray, num_clients: int, local_steps: int,
 
 @dataclasses.dataclass
 class EngineStats:
-    """Dispatch/sync accounting, the structural half of BENCH_round_engine."""
+    """Dispatch/sync accounting, held per block by tests/test_engine.py."""
 
     dispatches: int = 0              # jitted computations launched
     host_syncs: int = 0              # blocking device->host reads

@@ -1,12 +1,33 @@
-"""Production mesh factory. Functions only — importing this module never
-touches jax device state (jax locks the device count on first init, and the
-dry-run needs to set XLA_FLAGS before that happens)."""
+"""Mesh factories and the forced-device host environment. Functions only —
+importing this module never touches jax device state (jax locks the device
+count on first init, so a multi-device child must get its XLA_FLAGS from
+``multidev_env`` before that happens)."""
 from __future__ import annotations
 
-from typing import Tuple
+import os
+from typing import Dict, Tuple
 
 import jax
 from jax.sharding import AxisType
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
+
+
+def multidev_env() -> Dict[str, str]:
+    """Child environment for forced-8-device host-CPU runs: the XLA device
+    flag (must precede jax init), CPU platform pin, and src+repo on
+    PYTHONPATH. The one recipe for the tests' ``multidev`` subprocess
+    runner (tests/conftest.py), the IR-contract child of
+    ``scripts/check_static.py`` and ``benchmarks/bench_collectives``."""
+    env = os.environ.copy()
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=8").strip()
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(REPO, "src"), REPO]
+        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    return env
 
 
 def make_mesh(shape: Tuple[int, ...],
@@ -15,13 +36,6 @@ def make_mesh(shape: Tuple[int, ...],
     constraints and ``shard_map`` specs are written for GSPMD-partitioned
     axes, and ``jax.make_mesh`` defaults to ``Explicit`` ones."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
-    """TPU v5e pod slice: 16x16 = 256 chips per pod; 2 pods = 512 chips."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:

@@ -1,7 +1,6 @@
 """Serving driver: batched prefill + decode loop on a reduced config.
 
-Demonstrates the serving entry points actually executing (the production
-32k/500k shapes are exercised AOT by dryrun.py):
+Demonstrates the serving entry points actually executing:
 
     PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
         --batch 4 --prompt-len 32 --gen 16

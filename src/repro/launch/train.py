@@ -1,10 +1,9 @@
 """Federated training driver — the end-to-end entry point.
 
 Runs a real (executed, not dry-run) FL training job on whatever devices
-exist: paper vision models by name, or a reduced LM-family arch. The
-production-mesh path is exercised by dryrun.py; this driver is the
-"train a ~100M model for a few hundred rounds" deliverable and writes
-checkpoints + a metrics JSONL.
+exist: paper vision models by name, or a reduced LM-family arch. This
+driver is the "train a ~100M model for a few hundred rounds" deliverable
+and writes checkpoints + a metrics JSONL.
 
 Both paths drive ``repro.fl.engine.RoundEngine``: data and Dirichlet pools
 are device-resident, each eval block of ``--eval-every`` rounds is ONE

@@ -4,14 +4,15 @@ import sys
 
 import pytest
 
-# tests must see 1 CPU device (the 512-device flag is dryrun-only)
+# tests must see 1 CPU device; multi-device cases run in a child process
+# under ``multidev_env`` (see ``run_multidev``)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 sys.path.insert(0, REPO)  # benchmarks/
 
-from benchmarks.bench_collectives import multidev_env  # noqa: E402
+from repro.launch.mesh import multidev_env  # noqa: E402
 
 
 def run_multidev(args, timeout=1200):
@@ -19,7 +20,7 @@ def run_multidev(args, timeout=1200):
     devices. The device count is locked at first jax init, so multi-device
     sharding tests cannot run in the (single-device) pytest process itself —
     they run their scenario in a subprocess and assert on its exit status.
-    The environment recipe is shared with benchmarks/bench_collectives.
+    The environment recipe is ``repro.launch.mesh.multidev_env``.
     """
     import subprocess
     return subprocess.run([sys.executable] + list(args), env=multidev_env(),

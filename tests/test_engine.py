@@ -138,6 +138,18 @@ def test_engine_stats_accounting(world):
     assert eng2.stats.dispatches == 3 and eng2.stats.host_syncs == 6
 
 
+def test_warm_block_makes_no_host_to_device_transfer(world):
+    """Once the block is compiled, a block uploads nothing: batches are
+    gathered on the device from the resident set and the state stays
+    there, so a block under a disallowing transfer guard runs."""
+    eng, state = _engine(world, KINDS["threesfc"])
+    state, _ = eng.run_block(state, 2)
+    with jax.transfer_guard_host_to_device("disallow"):
+        state, ms = eng.run_block(state, 2)
+    assert np.isfinite(np.asarray(ms.loss)).all()
+    assert int(state.round) == 4
+
+
 def test_run_blocks_match_eval_cadence(world):
     """engine.run: metrics cover every round, evals land on the block ends
     (the seed cadence: every eval_every rounds plus the final round)."""
@@ -294,3 +306,18 @@ def test_benchmarks_run_only_badname_exits_2(capsys):
     assert "definitely_not_a_bench" in err
     for name in bench_run.BENCHES:
         assert name in err
+
+
+def test_benchmarks_run_exits_1_naming_a_failed_gate(monkeypatch, capsys):
+    """A driver whose result has a false ``pass`` fails the orchestrator,
+    which names it and not the drivers that passed."""
+    from benchmarks import run as bench_run
+    monkeypatch.setitem(bench_run.BENCHES, "faults",
+                        lambda quick: {"pass": True})
+    monkeypatch.setitem(bench_run.BENCHES, "wire",
+                        lambda quick: {"pass": False})
+    with pytest.raises(SystemExit) as e:
+        bench_run.main(["--only", "faults,wire"])
+    assert e.value.code == 1
+    err = capsys.readouterr().err
+    assert "wire" in err and "faults" not in err

@@ -128,8 +128,9 @@ ENTRY %main (p0: f32[8,16]) -> f32[8,16] {
 
 
 # ---------------------------------------------------------------------------
-# edge cases: the analyzer is fed arbitrary optimized-HLO text by benches and
-# the dryrun cost model — degenerate modules must yield zeros, not crashes
+# edge cases: the analyzer is fed arbitrary optimized-HLO text by the
+# contract checker and benches — degenerate modules must yield zeros, not
+# crashes
 # ---------------------------------------------------------------------------
 
 

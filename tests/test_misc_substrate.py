@@ -1,5 +1,4 @@
-"""Optimizers, checkpointing, data pipeline, roofline parser."""
-import os
+"""Optimizers, checkpointing, data pipeline."""
 
 import jax
 import jax.numpy as jnp
@@ -9,7 +8,6 @@ import pytest
 from repro.checkpoint import load_checkpoint, save_checkpoint
 from repro.data.synthetic import make_class_image_dataset, make_token_dataset
 from repro.optim import make_optimizer
-from repro.utils.roofline import Roofline, collective_bytes, model_flops_estimate
 
 
 @pytest.mark.parametrize("name", ["sgd", "momentum", "adam"])
@@ -65,40 +63,3 @@ def test_token_dataset_bigram_structure():
     for s in seqs:
         for a, b in zip(s[:-1], s[1:]):
             assert nxt.setdefault(int(a), int(b)) == int(b)
-
-
-def test_collective_bytes_parser():
-    hlo = """
-  %p = f32[1024,512]{1,0} parameter(0)
-  %ag = f32[4096,512]{1,0} all-gather(%p), replica_groups={{0,1,2,3}}, dimensions={0}
-  %ar = f32[1024,512]{1,0} all-reduce(%p), to_apply=%sum
-  %cp = f32[1024,512]{1,0} collective-permute(%p), source_target_pairs={{0,1}}
-  %done = f32[1024,512]{1,0} all-reduce-done(%ar)
-"""
-    out = collective_bytes(hlo)
-    leaf = 1024 * 512 * 4
-    assert out["all-gather"] == leaf
-    assert out["all-reduce"] == leaf
-    assert out["collective-permute"] == leaf
-    assert out["reduce-scatter"] == 0
-
-
-def test_roofline_terms():
-    r = Roofline(flops=197e12, hbm_bytes=819e9, coll_bytes={"all-reduce": 50e9},
-                 chips=256, model_flops=197e12 * 256 * 0.5)
-    assert abs(r.compute_s - 1.0) < 1e-9
-    assert abs(r.memory_s - 1.0) < 1e-9
-    assert abs(r.collective_s - 1.0) < 1e-9
-    assert abs(r.useful_ratio - 0.5) < 1e-9
-
-
-def test_model_flops_estimate_dense_vs_moe():
-    from repro.configs.base import get_config
-    dense = model_flops_estimate(get_config("tinyllama-1.1b"), 1e6)
-    # tinyllama ~1.1B params -> 6*N*D ~ 6.6e15 for 1M tokens
-    assert 4e15 < dense < 9e15
-    moe = model_flops_estimate(get_config("qwen3-moe-30b-a3b"), 1e6)
-    moe_total_like = model_flops_estimate(
-        get_config("qwen3-moe-30b-a3b").replace(num_experts=0, experts_per_token=0,
-                                                d_ff=768 * 128), 1e6)
-    assert moe < 0.3 * moe_total_like     # active << total for 8/128 experts

@@ -1,6 +1,5 @@
 """Sharding rules: specs are rank-correct, divisibility-safe, and the FL
-round + serving entries lower & compile on a small host mesh (the same code
-path dryrun.py uses at 16x16 and 2x16x16)."""
+round + serving entries lower & compile on a small host mesh."""
 import os
 
 import jax
@@ -19,7 +18,7 @@ from repro.models.build import build_model
 # multi-device half in a forced-8-device child and work from any parent
 needs_multidev = pytest.mark.skipif(
     len(jax.devices()) < 2 and os.environ.get("FORCE_SHARDING_TESTS") != "1",
-    reason="needs >=2 devices (run under dryrun flags for multi-dev)")
+    reason="needs >=2 devices (run under repro.launch.mesh.multidev_env)")
 
 
 def _mesh():

@@ -1,6 +1,6 @@
-"""§Perf variant paths must lower on a host mesh (the exact code paths the
-hillclimb driver exercises at 256/512 chips): fused decode, reduced-precision
-EF, the no-qk-hd sharding rule, and activation-sharding pins."""
+"""§Perf variant paths must lower on a host mesh: fused decode,
+reduced-precision EF, the no-qk-hd sharding rule, and activation-sharding
+pins."""
 import os
 
 import jax
@@ -11,7 +11,8 @@ from repro.launch import specs as specs_lib
 from repro.launch.mesh import make_mesh
 
 pytestmark = pytest.mark.skipif(
-    len(jax.devices()) < 2, reason="needs >=2 devices (see dryrun flags)")
+    len(jax.devices()) < 2,
+    reason="needs >=2 devices (run under repro.launch.mesh.multidev_env)")
 
 SMALL = {"train_4k": ShapeConfig("train_4k", 64, 8, "train"),
          "prefill_32k": ShapeConfig("prefill_32k", 64, 4, "prefill")}
